@@ -52,9 +52,9 @@ Cold rows (``sweep_padded``, ``sweep_fused_cold``) clear the
 in-process executable caches AND the bench-local disk cache before
 EVERY iteration, so every rep genuinely compiles (the committed
 baseline used to report best-of-reps where only rep 1 compiled).  The
-whole bench runs against a throwaway persistent-cache directory and
-restores the prior cache wiring on exit, so it never pollutes
-``~/.cache/repro-jax``.
+whole bench runs against its own fixed subdirectory of the resolved
+persistent-cache directory (``<cache>/bench-campaign``, emptied at
+start) and restores the prior cache wiring on exit.
 
 The traces are sampled at a fixed RNG seed, so the grid is identical
 run-to-run and numbers are comparable across commits — provided the
@@ -69,7 +69,6 @@ from __future__ import annotations
 import json
 import os
 import shutil
-import tempfile
 import time
 from typing import List, Optional
 
@@ -128,13 +127,24 @@ def _timed_campaign(label, lines, results, fn, reps: int = 1,
     return res
 
 
+def bench_cache_dir(name: str) -> str:
+    """``<cache>/<name>``: a fixed subdirectory of the resolved
+    persistent cache directory (``JAX_COMPILATION_CACHE_DIR`` or the
+    checkout's ``.repro-cache``)."""
+    base = compilecache.default_cache_dir()
+    if base is None:
+        raise RuntimeError("the cache benchmarks need JAX's persistent "
+                           "compilation cache; it is switched off")
+    return os.path.join(base, name)
+
+
 def run(out_path: str = "BENCH_campaign.json", shard: bool = False,
         chunk_size: Optional[int] = None) -> List[str]:
-    # hermetic persistent cache: the bench measures cold/warm-disk
-    # regimes against its own throwaway directory and restores the
-    # prior wiring on exit (never touches ~/.cache/repro-jax)
+    # the bench measures cold/warm-disk regimes against its own fixed
+    # subdirectory of the resolved cache directory, which the cold rows
+    # empty themselves, and restores the prior wiring on exit
     prev_dir = compilecache.persistent_cache_dir()
-    cache_dir = tempfile.mkdtemp(prefix="bench-repro-cache-")
+    cache_dir = bench_cache_dir("bench-campaign")
     compilecache.enable_persistent_cache(cache_dir)
 
     def clear_disk():
@@ -154,12 +164,11 @@ def run(out_path: str = "BENCH_campaign.json", shard: bool = False,
         if i == 0:
             clear_disk()
 
+    clear_disk()
     try:
         return _run_rows(out_path, shard, chunk_size, cold_iter,
                          diskwarm_iter)
     finally:
-        clear_disk()
-        shutil.rmtree(cache_dir, ignore_errors=True)
         if prev_dir is not None:
             compilecache.enable_persistent_cache(prev_dir)
         else:

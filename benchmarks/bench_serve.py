@@ -31,13 +31,13 @@ from __future__ import annotations
 
 import json
 import shutil
-import tempfile
 import time
 from typing import List
 
 import jax
 import numpy as np
 
+from benchmarks.bench_campaign import bench_cache_dir
 from benchmarks.datasets import prepare
 from repro.core import compilecache
 from repro.core.processes import (ClusterCascadeProcess, IidRateProcess,
@@ -128,13 +128,15 @@ def _time_service(label, bank, wins, failure, results, lines,
 
 
 def run(out_path: str = "BENCH_serve.json") -> List[str]:
+    # a fixed subdirectory of the resolved cache directory, emptied
+    # first so the bank export and bucket builds start cold
     prev_dir = compilecache.persistent_cache_dir()
-    cache_dir = tempfile.mkdtemp(prefix="bench-serve-cache-")
+    cache_dir = bench_cache_dir("bench-serve")
+    shutil.rmtree(cache_dir, ignore_errors=True)
     compilecache.enable_persistent_cache(cache_dir)
     try:
         return _run_rows(out_path)
     finally:
-        shutil.rmtree(cache_dir, ignore_errors=True)
         if prev_dir is not None:
             compilecache.enable_persistent_cache(prev_dir)
         else:

@@ -43,7 +43,8 @@ from repro.analysis.plancheck import budgets as _budgets
 from repro.analysis.plancheck.findings import Finding, finding
 
 #: primitive names that imply a host round-trip inside the program
-HOST_SYNC_PRIMS = {"infeed", "outfeed", "host_callback"}
+HOST_SYNC_PRIMS = {"infeed", "outfeed", "host_callback",
+                   "debug_print"}     # jax.debug.print on jax >= 0.9
 _SYNC_SUBSTRING = "callback"          # pure_callback / io_callback /
 #                                       debug_callback / custom variants
 

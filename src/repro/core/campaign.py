@@ -408,12 +408,15 @@ _AOT_LOCK = threading.Lock()
 #: AOT bucket compiles pass this explicit (default-valued, so codegen
 #: is unchanged) option override: it lands in the compile-options hash,
 #: giving AOT compiles a module-cache key DISJOINT from the jit path's.
-#: An executable that XLA's persistent module cache served cannot be
-#: re-serialised ("Symbols not found" on reload, jaxlib 0.4.36 CPU), so
-#: an AOT compile must never be satisfied by a module entry a previous
-#: jit-path run wrote — the key split guarantees a genuine, whole-
-#: serialisable compile without touching global config (the host's
-#: utility jits keep caching normally while the worker pool compiles).
+#: On jax/jaxlib 0.9.0 an XLA:CPU executable that the persistent module
+#: cache served does not survive re-serialisation (the reloaded payload
+#: fails with "Function ... not found"), so an AOT compile must never be
+#: satisfied by a module entry a previous jit-path run wrote — the key
+#: split guarantees a genuine, whole-serialisable compile without
+#: touching global config (the host's utility jits keep caching normally
+#: while the worker pool compiles).  On a TPU v5e the same round trip
+#: loads and runs; the split is kept on every backend so the engine has
+#: one path, at the price of separate module-cache entries.
 _AOT_COMPILER_OPTIONS = {"xla_embed_ir_in_executable": False}
 
 
@@ -425,6 +428,15 @@ def _avals_signature(abstract_args) -> tuple:
     return (str(treedef),
             tuple((tuple(l.shape), jnp.dtype(l.dtype).name)
                   for l in leaves))
+
+
+def _lowering_config() -> tuple:
+    """Global settings that change the lowered program without changing
+    the avals — the default matmul precision (``float32`` on a TPU is a
+    six-pass program, the default one bfloat16 pass) — so executables
+    compiled under different settings never share a cache entry."""
+    return (("matmul_precision",
+             str(jax.config.jax_default_matmul_precision)),)
 
 
 def aot_executable(kind: str, model: ModelLike, cfg, k_pad, ndev,
@@ -441,7 +453,7 @@ def aot_executable(kind: str, model: ModelLike, cfg, k_pad, ndev,
     builds data arrays."""
     from repro.core import compilecache as _cc
     key = _exe_key(kind, model, cfg, k_pad, ndev, track_iso, fused)
-    full_key = key + _avals_signature(abstract_args)
+    full_key = key + _avals_signature(abstract_args) + _lowering_config()
     with _AOT_LOCK:
         hit = _AOT_CACHE.get(full_key)
     if hit is not None:

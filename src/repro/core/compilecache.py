@@ -15,8 +15,8 @@ invoking XLA.
   campaign core is cached.  Idempotent; safe to call repeatedly.
 * :func:`ensure_persistent_cache` — the lazy entry point
   ``experiment.execute`` calls: enables the default cache once per
-  process unless the user opted out (``REPRO_CACHE_DIR=off``) or
-  already enabled a custom dir.
+  process unless JAX's cache is switched off or a directory was
+  already chosen explicitly.
 * :func:`xla_compile_stats` — process-wide counters of persistent-cache
   compile requests / hits / misses, fed by a ``jax.monitoring`` event
   listener.  ``misses`` counts ACTUAL XLA compiles; a warm-disk re-run
@@ -31,12 +31,16 @@ invoking XLA.
   only short-circuits compilation, and for campaign-sized programs the
   Python trace/lower step costs seconds of its own.
 
-Environment:
+Environment (JAX's own variables):
 
-``REPRO_CACHE_DIR``
-    Overrides the cache directory.  The values ``off`` / ``none`` /
-    ``0`` / empty disable the persistent cache entirely.  Unset, the
-    cache lives under ``~/.cache/repro-jax``.
+``JAX_COMPILATION_CACHE_DIR``
+    The cache directory.  When set, it is used as given and no other
+    directory is chosen.  Unset, the cache lives at a fixed path inside
+    the checkout, ``<repo>/.repro-cache`` (:data:`REPO_CACHE_DIR`): the
+    path is part of nothing that moves, so every process of a checkout
+    finds the same entries.
+``JAX_ENABLE_COMPILATION_CACHE=false``
+    Switches the persistent cache off.
 """
 from __future__ import annotations
 
@@ -48,11 +52,15 @@ from typing import Any, Dict, Optional
 
 import jax
 
-ENV_VAR = "REPRO_CACHE_DIR"
+ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
+#: the cache directory when ``JAX_COMPILATION_CACHE_DIR`` is unset:
+#: ``.repro-cache`` at the root of the checkout (``src/repro/core`` up three)
+REPO_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))), ".repro-cache")
 #: set REPRO_CACHE_DEBUG=1 to surface the otherwise-swallowed
 #: executable-cache store/load failures on stderr
 DEBUG_VAR = "REPRO_CACHE_DEBUG"
-_OFF_VALUES = {"", "0", "off", "none", "disabled", "false"}
 
 
 def _debug(msg: str, exc: Exception) -> None:
@@ -72,14 +80,15 @@ _state = {"dir": None, "listening": False, "ensured": False}
 
 
 def default_cache_dir() -> Optional[str]:
-    """Resolved cache directory: ``REPRO_CACHE_DIR`` override first
-    (``off``-like values -> None = disabled), else ``~/.cache/repro-jax``."""
+    """Resolved cache directory: ``JAX_COMPILATION_CACHE_DIR`` when set,
+    else :data:`REPO_CACHE_DIR`; None when JAX's cache is switched off
+    (``JAX_ENABLE_COMPILATION_CACHE=false``)."""
+    if not jax.config.jax_enable_compilation_cache:
+        return None
     env = os.environ.get(ENV_VAR)
-    if env is not None:
-        if env.strip().lower() in _OFF_VALUES:
-            return None
+    if env:
         return os.path.abspath(os.path.expanduser(env))
-    return os.path.expanduser(os.path.join("~", ".cache", "repro-jax"))
+    return REPO_CACHE_DIR
 
 
 def _listener(event: str, **kwargs) -> None:
@@ -102,7 +111,7 @@ def _register_listener() -> None:
 def enable_persistent_cache(path: Optional[str] = None) -> Optional[str]:
     """Enable the on-disk compilation cache at ``path`` (default:
     :func:`default_cache_dir`); returns the directory in use, or None
-    when disabled via ``REPRO_CACHE_DIR``.  Re-pointing at a new
+    when JAX's cache is switched off.  Re-pointing at a new
     directory resets JAX's in-process handle so subsequent compiles hit
     the new location."""
     path = default_cache_dir() if path is None else os.path.abspath(
@@ -134,8 +143,8 @@ def disable_persistent_cache() -> None:
 
 def ensure_persistent_cache() -> Optional[str]:
     """Lazy default wiring: the first ``execute()`` of a process lands
-    here and enables the default directory, honouring ``REPRO_CACHE_DIR``
-    opt-out and never overriding an explicit
+    here and enables the default directory, honouring JAX's off switch
+    and never overriding an explicit
     :func:`enable_persistent_cache` / :func:`disable_persistent_cache`
     call made earlier."""
     if _state["ensured"] or _state["dir"] is not None:
@@ -218,8 +227,8 @@ def store_executable(fingerprint: str, compiled: Any) -> None:
         # an entry on disk must be an entry that LOADS: round-trip the
         # payload before persisting.  Serialisation can silently produce
         # an unloadable payload (an executable served from XLA's module
-        # cache drops its jit-compiled symbols — see
-        # campaign._module_cache_disabled), and a poisoned entry would
+        # cache drops its jit-compiled symbols on the CPU — see
+        # campaign._AOT_COMPILER_OPTIONS), and a poisoned entry would
         # force every future process through a fail-load + recompile.
         _se.deserialize_and_load(*payload)
         os.makedirs(d, exist_ok=True)

@@ -36,13 +36,6 @@ from repro.optim.optimizers import apply_updates, make_optimizer
 from repro.sharding import logical as L
 
 
-#: version-portable shard_map shared with the campaign scenario-sharding
-#: executor; see :mod:`repro.sharding.logical` for the CPU/0.4.x
-#: full-manual fallback rationale.
-_FULL_MANUAL_FALLBACK = L.FULL_MANUAL_FALLBACK
-_partial_manual_shard_map = L.compat_shard_map
-
-
 def data_axis_size(mesh: Mesh) -> int:
     sizes = L.mesh_axis_sizes(mesh)
     return sizes.get("data", 1)
@@ -297,13 +290,8 @@ def make_ring_train_step(mcfg: ModelConfig, tolfl: TolFLConfig,
         n_fin = jax.lax.psum(carry_n * is_final, axes)
         return g_fin, l_fin, n_fin
 
-    # with the full-manual fallback every mesh axis is inside the
-    # region, so sharding constraints must omit them all
-    ctx_axes = (tuple(mesh.axis_names) if _FULL_MANUAL_FALLBACK
-                else manual)
-
     def per_shard(params, batch, alive, gidx):
-        with L.manual_axes(ctx_axes):
+        with L.manual_axes(manual):
             return _per_shard(params, batch, alive, gidx)
 
     def _per_shard(params, batch, alive, gidx):
@@ -365,10 +353,9 @@ def make_ring_train_step(mcfg: ModelConfig, tolfl: TolFLConfig,
         # leading batch dim over the federated axes
         batch_specs = jax.tree.map(
             lambda v: PS(manual, *([None] * (v.ndim - 1))), batch)
-        sm = _partial_manual_shard_map(per_shard, mesh,
-                                       (PS(), batch_specs, PS(),
-                                        PS(manual)),
-                                       out_specs, manual)
+        sm = L.manual_shard_map(per_shard, mesh,
+                                (PS(), batch_specs, PS(), PS(manual)),
+                                out_specs, manual)
         gidx = jnp.arange(p_sz * d_sz, dtype=jnp.int32)
         g, loss, n_tot = sm(state["params"], batch, alive, gidx)
         has_update = (n_tot > 0).astype(jnp.float32)

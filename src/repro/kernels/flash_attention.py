@@ -24,6 +24,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -90,11 +91,11 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     causal: bool = True, window: Optional[int] = None,
                     q_block: int = 256, kv_block: int = 256,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool = False) -> jax.Array:
     """q: (B, S, H, D); k, v: (B, Sk, KVH, D); H % KVH == 0.
 
-    interpret=True executes the kernel body on CPU (this container);
-    interpret=False is the TPU target path.
+    ``interpret=True`` runs the kernel body on any backend
+    (:mod:`repro.kernels.ops` chooses by backend).
     """
     B, Sq, H, D = q.shape
     _, Sk, KVH, _ = k.shape
@@ -126,20 +127,12 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         out_specs=pl.BlockSpec((1, q_block, G, D), lambda b, i, j: (b, i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B * KVH, Sq, G, D), q.dtype),
         scratch_shapes=[
-            _scratch((q_block, G), jnp.float32),
-            _scratch((q_block, G), jnp.float32),
-            _scratch((q_block, G, D), jnp.float32),
+            pltpu.VMEM((q_block, G), jnp.float32),
+            pltpu.VMEM((q_block, G), jnp.float32),
+            pltpu.VMEM((q_block, G, D), jnp.float32),
         ],
         interpret=interpret,
     )(qr, kr, vr)
     return out.reshape(B, KVH, Sq, G, D).transpose(0, 2, 1, 3, 4) \
         .reshape(B, Sq, H, D)
 
-
-def _scratch(shape, dtype):
-    from jax.experimental.pallas import tpu as pltpu
-    try:
-        return pltpu.VMEM(shape, dtype)
-    except Exception:  # pragma: no cover
-        import jax.experimental.pallas as pl_
-        return pl_.MemorySpace.ANY(shape, dtype)  # type: ignore

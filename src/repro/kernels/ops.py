@@ -1,12 +1,13 @@
 """Jit'd public wrappers for the Pallas kernels.
 
 One entry point per kernel with a ``backend`` switch:
-  * "pallas"     — pl.pallas_call, interpret=True on CPU (this container),
-                   interpret=False on real TPU.
+  * "pallas"     — pl.pallas_call, compiled on a TPU and run by the
+                   Pallas interpreter on any other backend.
   * "xla"        — the pure-jnp reference path (what the dry-run lowers;
                    also the oracle used by the parity tests).
 
-The model code takes ``use_pallas`` flags and routes through these.
+The model code takes ``use_pallas`` flags and routes through these; the
+kernels themselves default to compiled mode.
 """
 from __future__ import annotations
 

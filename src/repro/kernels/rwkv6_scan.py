@@ -19,8 +19,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from repro.kernels.flash_attention import _scratch
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, sout_ref,
@@ -58,7 +57,7 @@ def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, sout_ref,
 @functools.partial(jax.jit, static_argnames=("t_block", "interpret"))
 def rwkv6_scan(r: jax.Array, k: jax.Array, v: jax.Array, w: jax.Array,
                u: jax.Array, state0: jax.Array, t_block: int = 64,
-               interpret: bool = True) -> Tuple[jax.Array, jax.Array]:
+               interpret: bool = False) -> Tuple[jax.Array, jax.Array]:
     """r,k,v,w: (B,S,H,N) f32; u: (H,N); state0: (B,H,N,N).
 
     Returns (y (B,S,H,N), final state (B,H,N,N))."""
@@ -94,7 +93,7 @@ def rwkv6_scan(r: jax.Array, k: jax.Array, v: jax.Array, w: jax.Array,
             jax.ShapeDtypeStruct((B * H, S, N), jnp.float32),
             jax.ShapeDtypeStruct((B * H, N, N), jnp.float32),
         ],
-        scratch_shapes=[_scratch((N, N), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((N, N), jnp.float32)],
         interpret=interpret,
     )(rr, kk, vv, ww, uu, s0)
     y = y.reshape(B, H, S, N).transpose(0, 2, 1, 3)

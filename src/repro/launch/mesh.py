@@ -10,19 +10,20 @@ from __future__ import annotations
 import jax
 
 from repro.configs.base import MeshConfig
+from repro.sharding.logical import make_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh_from_config(cfg: MeshConfig):
     if cfg.multi_pod:
-        return jax.make_mesh((cfg.pods, cfg.data, cfg.model),
-                             ("pod", "data", "model"))
-    return jax.make_mesh((cfg.data, cfg.model), ("data", "model"))
+        return make_mesh((cfg.pods, cfg.data, cfg.model),
+                         ("pod", "data", "model"))
+    return make_mesh((cfg.data, cfg.model), ("data", "model"))
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
@@ -30,4 +31,4 @@ def make_host_mesh(data: int = 1, model: int = 1):
     n = len(jax.devices())
     data = min(data, n)
     model = max(1, min(model, n // max(data, 1)))
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))

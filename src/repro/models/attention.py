@@ -241,8 +241,8 @@ def attn_apply(p: P.Params, x: jax.Array, cfg: AttentionConfig,
     q, k, v = project_qkv(p, x, cfg, positions, norm_eps,
                           compute_dtype=x.dtype)
     if use_pallas:
-        from repro.kernels import flash_attention as fa
-        out = fa.flash_attention(q, k, v, causal=causal, window=window)
+        from repro.kernels import ops
+        out = ops.attention(q, k, v, causal=causal, window=window)
     else:
         out = blocked_attention(q, k, v, causal=causal, window=window,
                                 q_block=q_block, kv_block=kv_block)

@@ -73,8 +73,8 @@ def _lru_scan(a_t: jax.Array, b_t: jax.Array, h0: Optional[jax.Array],
               use_pallas: bool = False) -> jax.Array:
     """h_t = a_t * h_{t-1} + b_t over axis 1.  a_t, b_t: (B,S,W) float32."""
     if use_pallas:
-        from repro.kernels import rglru_scan as ker
-        return ker.rglru_scan(a_t, b_t, h0)
+        from repro.kernels import ops
+        return ops.rglru(a_t, b_t, h0)
     if h0 is not None:
         # fold the carried state into the first step
         b_t = b_t.at[:, 0, :].add(a_t[:, 0, :] * h0)

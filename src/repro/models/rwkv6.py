@@ -120,8 +120,8 @@ def timemix_apply(p: P.Params, x: jax.Array, cfg: ModelConfig,
     u = p["bonus"].astype(jnp.float32).reshape(H, N)
     r32, k32, v32, w32 = (t.astype(jnp.float32) for t in (r, k, v, w))
     if use_pallas:
-        from repro.kernels import rwkv6_scan as ker
-        y, wkv = ker.rwkv6_scan(r32, k32, v32, w32, u, wkv0)
+        from repro.kernels import ops
+        y, wkv = ops.rwkv6(r32, k32, v32, w32, u, wkv0)
     else:
         y, wkv = _wkv_scan(r32, k32, v32, w32, u, wkv0)
     y = y.reshape(B, S, d)

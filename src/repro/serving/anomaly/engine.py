@@ -43,7 +43,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.campaign import (_AOT_COMPILER_OPTIONS, AotTimes,
-                                 _avals_signature)
+                                 _avals_signature, _lowering_config)
 from repro.core.failure import trace_alive_mask
 from repro.models import detector as D
 from repro.models.detector import ModelLike
@@ -83,7 +83,7 @@ def score_executable(model: ModelLike, abstract_args
     from repro.core import compilecache as _cc
     _cc.ensure_persistent_cache()
     key = (("serve_score", D.canonical_model_key(model))
-           + _avals_signature(abstract_args))
+           + _avals_signature(abstract_args) + _lowering_config())
     with _SCORE_LOCK:
         hit = _SCORE_CACHE.get(key)
     if hit is not None:

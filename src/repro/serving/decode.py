@@ -299,8 +299,8 @@ def _attn_prefill(p, h, cfg: ModelConfig, kind: str, use_pallas: bool):
     q, k, v = A.project_qkv(p, h, a, jnp.arange(S), cfg.norm_eps,
                             compute_dtype=h.dtype)
     if use_pallas:
-        from repro.kernels import flash_attention as fa
-        out = fa.flash_attention(q, k, v, causal=True, window=window)
+        from repro.kernels import ops
+        out = ops.attention(q, k, v, causal=True, window=window)
     else:
         out = A.blocked_attention(q, k, v, causal=True, window=window)
     out = out.reshape(B, S, a.num_heads * a.head_dim)

@@ -1,10 +1,10 @@
-from repro.sharding.logical import (FULL_MANUAL_FALLBACK, activate_mesh,
-                                    compat_shard_map, constrain,
+from repro.sharding.logical import (activate_mesh, constrain,
                                     current_mesh, current_rules,
+                                    make_mesh, manual_shard_map,
                                     mesh_axis_sizes, rules_for,
                                     scenario_shard_map, sharding_for,
                                     spec_for)
 
-__all__ = ["FULL_MANUAL_FALLBACK", "activate_mesh", "compat_shard_map",
-           "constrain", "current_mesh", "current_rules", "mesh_axis_sizes",
-           "rules_for", "scenario_shard_map", "sharding_for", "spec_for"]
+__all__ = ["activate_mesh", "constrain", "current_mesh", "current_rules",
+           "make_mesh", "manual_shard_map", "mesh_axis_sizes", "rules_for",
+           "scenario_shard_map", "sharding_for", "spec_for"]

@@ -17,8 +17,9 @@ from repro.data import commsml, federated
 @pytest.fixture(scope="session", autouse=True)
 def _hermetic_compile_cache(tmp_path_factory):
     """Point the persistent compilation cache at a per-session temp
-    directory so the suite never writes ``~/.cache/repro-jax``.  An
-    explicit ``REPRO_CACHE_DIR`` still wins (the cross-process
+    directory, so every session starts cold and tests that count
+    compiles see only their own.  An explicit
+    ``JAX_COMPILATION_CACHE_DIR`` still wins (the cross-process
     disk-cache tests set it in their subprocess env, not here)."""
     if os.environ.get(compilecache.ENV_VAR):
         yield

@@ -16,8 +16,8 @@ What is proven:
   during ``plan()`` (it used to fire zero or twice depending on the
   entry point).
 * **cross-process disk cache** — a second process running the same spec
-  against the same ``REPRO_CACHE_DIR`` reports zero XLA compiles, zero
-  traces and a bit-identical result digest.
+  against the same ``JAX_COMPILATION_CACHE_DIR`` reports zero XLA
+  compiles, zero traces and a bit-identical result digest.
 """
 import dataclasses
 import json
@@ -140,6 +140,24 @@ def test_aot_disk_warm_skips_tracing(small_ae, small_data):
     _assert_identical(res_ref, res_disk)
 
 
+def test_matmul_precision_compiles_its_own_executables(small_ae,
+                                                       small_data):
+    """The default matmul precision changes the lowered program but not
+    the avals: a run under another precision compiles its own bucket
+    executables instead of reusing the default ones from memory or
+    disk."""
+    spec = _spec(small_ae, small_data, aot=True)
+    run_experiment(spec)
+    prev = jax.config.jax_default_matmul_precision
+    jax.config.update("jax_default_matmul_precision", "float32")
+    try:
+        res = run_experiment(spec)
+    finally:
+        jax.config.update("jax_default_matmul_precision", prev)
+    assert [b.cache for b in res.compile_report.buckets] == \
+        ["compiled"] * 3
+
+
 # ---------------------------------------------------------------------------
 # warn-once (shard degrade)
 # ---------------------------------------------------------------------------
@@ -204,16 +222,17 @@ print(json.dumps({
 
 
 def test_disk_cache_second_process_zero_xla_compiles(tmp_path):
-    """The PR's headline contract: a fresh process re-running a spec
-    against a populated ``REPRO_CACHE_DIR`` performs ZERO XLA compiles
-    and ZERO traces — every bucket executable deserialises whole — and
-    the results are bit-identical to the process that compiled them."""
+    """The disk-cache contract: a fresh process re-running a spec
+    against a populated ``JAX_COMPILATION_CACHE_DIR`` performs ZERO XLA
+    compiles and ZERO traces — every bucket executable deserialises
+    whole — and the results are bit-identical to the process that
+    compiled them."""
     # repro is a namespace package (__file__ is None): derive src/ from
     # a real module inside it
     src = os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(campaign.__file__))))
     env = dict(os.environ,
-               REPRO_CACHE_DIR=str(tmp_path / "cache"),
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"),
                PYTHONPATH=os.pathsep.join(
                    p for p in (src, os.environ.get("PYTHONPATH")) if p))
 

@@ -7,8 +7,12 @@
   freedom that changes the lowered program distinct (no stale-program
   collisions), and the AOT layer's abstract-argument signature must
   separate executables per shape/dtype/tree.
+* Persistent-cache placement: ``JAX_COMPILATION_CACHE_DIR`` when set,
+  else the checkout's fixed ``.repro-cache``.
 """
 import dataclasses
+import os
+import tempfile
 
 import jax
 import jax.numpy as jnp
@@ -18,6 +22,7 @@ import pytest
 from repro.configs import ARCHS
 from repro.configs.autoencoder_paper import AutoencoderConfig
 from repro.core import campaign as C
+from repro.core import compilecache
 from repro.core.baselines import MultiModelConfig
 from repro.core.campaign import ExecPlan
 from repro.core.simulate import SimConfig
@@ -227,3 +232,30 @@ def test_avals_signature_separates_executables():
                                 "y": sds((3,), jnp.int32)})
             == C._avals_signature({"y": sds((3,), jnp.int32),
                                    "x": sds((2,), jnp.float32)}))
+
+
+# ---------------------------------------------------------------------------
+# persistent-cache placement
+# ---------------------------------------------------------------------------
+def test_default_cache_dir_placement(monkeypatch, tmp_path):
+    """The directory comes from ``JAX_COMPILATION_CACHE_DIR`` when set,
+    else is ``<repo>/.repro-cache`` — a fixed path, never one made from
+    a temporary name or the pid (the path is part of every entry's
+    key); JAX's off switch disables it."""
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "c"))
+    assert compilecache.default_cache_dir() == str(tmp_path / "c")
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    d = compilecache.default_cache_dir()
+    assert d == os.path.join(repo, ".repro-cache")
+    assert d == compilecache.default_cache_dir()
+    assert not d.startswith(tempfile.gettempdir())
+    assert str(os.getpid()) not in d
+
+    prev = jax.config.jax_enable_compilation_cache
+    try:
+        jax.config.update("jax_enable_compilation_cache", False)
+        assert compilecache.default_cache_dir() is None
+    finally:
+        jax.config.update("jax_enable_compilation_cache", prev)

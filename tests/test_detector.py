@@ -34,10 +34,13 @@ from repro.core.simulate import comm_mb_per_round
 from repro.data import commsml
 from repro.models import detector as D
 
-# captured on the pre-refactor tree (see the module docstring): a whole
-# tolfl/fl/ifca campaign's result arrays, hashed in execution order
+# a whole tolfl/fl/ifca campaign's result arrays, hashed in execution
+# order.  Captured on the pre-refactor tree under jax 0.4.37 (see the
+# module docstring), then re-captured with the engine unchanged under
+# jax/jaxlib 0.9.0 on the CPU, whose XLA and RNG defaults give
+# different float bits.
 PRE_REFACTOR_DIGEST = \
-    "89d183d7bdcd80bc6a8703b4145d5735d26d03d8823120ff3b534598c8595261"
+    "aaa3b9c40394ddcaf10b90e0525a8859288c2dbea8981284a2db6f6a2520218a"
 # sha256(repr(_exe_key(...))) of a representative fused single key
 PRE_REFACTOR_FINGERPRINT = \
     "7c37de1094b392f2ceec5b6c253dd57e8e44e6100e5c081c63bd3bfe7fc87c13"

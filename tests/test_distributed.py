@@ -38,7 +38,7 @@ SCRIPT = textwrap.dedent("""
     from repro.sharding import logical as L
 
     assert jax.device_count() == 8, jax.device_count()
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = L.make_mesh((4, 2), ("data", "model"))
     rules = L.rules_for("replicated_data")
 
     cfg = ModelConfig(name="tiny", num_layers=2, d_model=64, d_ff=128,
@@ -63,9 +63,8 @@ SCRIPT = textwrap.dedent("""
         with L.activate_mesh(mesh, rules):
             step = D.make_train_step(cfg, tolfl, ocfg, mesh)
             new_state, metrics = jax.jit(step)(state, batch, alive)
-        # flatten leaf-by-leaf on the HOST: eager jnp.concatenate over
-        # differently-sharded leaves (replicated ring vs model-sharded
-        # psum outputs) scrambles block order on this jax/CPU version
+        # flatten leaf-by-leaf on the host (the ring and psum outputs
+        # carry different shardings)
         flat = np.concatenate([
             np.asarray(jax.device_get(x)).astype(np.float32).ravel()
             for x in jax.tree.leaves(new_state["params"])])

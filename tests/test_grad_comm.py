@@ -32,7 +32,7 @@ SCRIPT = textwrap.dedent("""
     from repro.core import distributed as D
     from repro.sharding import logical as L
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = L.make_mesh((4, 2), ("data", "model"))
     rules = L.rules_for("replicated_data")
     cfg = ModelConfig(name="tiny", num_layers=2, d_model=64, d_ff=128,
                       vocab_size=256,
