@@ -53,6 +53,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.failure import (Failure, FailureTrace, KIND_CODES,
                                 MAX_EVENTS, NO_FAILURE, PAD_EPOCH,
                                 trace_alive_mask, trace_faulty_scale)
@@ -209,8 +210,8 @@ def _split_trace(trace: FailureTrace) -> Tuple[FailureTrace, FailureTrace]:
 
 def prepare_multimodel_arrays(device_x: np.ndarray,
                               device_counts: np.ndarray):
-    dx = jnp.asarray(device_x)
-    counts = jnp.asarray(device_counts, jnp.float32)
+    dx = obs.to_device(device_x)
+    counts = obs.to_device(device_counts, jnp.float32)
     valid = (jnp.arange(device_x.shape[1])[None, :]
              < counts[:, None]).astype(jnp.float32)
     return dx, counts, valid
@@ -258,97 +259,112 @@ def _build_multimodel_core(model: ModelLike, cfg: MultiModelConfig):
         m_live = jnp.maximum(jnp.sum(model_valid), 1.0).astype(jnp.int32)
 
         # ---- initial assignment ----
-        if cfg.scheme == "fedgroup":
-            k_probe, k_pgrad, k_km = jax.random.split(k_group, 3)
-            p0 = det.init_params(k_probe)
-            g0 = jax.vmap(lambda x, v, k_: _flat(grad_fn(p0, x, v, k_)),
-                          in_axes=(0, 0, 0))(
-                dx, valid, jax.random.split(k_pgrad, N))
-            assign0 = _kmeans_groups(g0, M, k_km,
-                                     center_valid=model_valid)
-        else:
-            assign0 = jnp.arange(N) % m_live
+        with jax.named_scope("assign"):
+            if cfg.scheme == "fedgroup":
+                k_probe, k_pgrad, k_km = jax.random.split(k_group, 3)
+                p0 = det.init_params(k_probe)
+                g0 = jax.vmap(lambda x, v, k_: _flat(grad_fn(p0, x, v, k_)),
+                              in_axes=(0, 0, 0))(
+                    dx, valid, jax.random.split(k_pgrad, N))
+                assign0 = _kmeans_groups(g0, M, k_km,
+                                         center_valid=model_valid)
+            else:
+                assign0 = jnp.arange(N) % m_live
 
         def device_losses(models_, x, v, k_):
             """(M,) local loss of each model instance on one device."""
             return jax.vmap(lambda p: local_loss(p, x, v, k_))(models_)
 
         def round_fn(carry, epoch):
+            # each stage under a jax.named_scope (obs.MULTI_SCOPES): HLO
+            # op metadata only, the program is unchanged
             models_, assign, rkey = carry
-            rkey, dkey = jax.random.split(rkey)
-            dkeys = jax.random.split(dkey, N)
-            a_dev = trace_alive_mask(client_tr, N, epoch)
-            a_grp = trace_alive_mask(server_tr, M, epoch)
+            with jax.named_scope("local_grad"):
+                rkey, dkey = jax.random.split(rkey)
+                dkeys = jax.random.split(dkey, N)
+            with jax.named_scope("alive_mask"):
+                a_dev = trace_alive_mask(client_tr, N, epoch)
+                a_grp = trace_alive_mask(server_tr, M, epoch)
 
             # ---- (re)assignment ----
             # padded model slots never win: their loss/distance is +inf
-            if cfg.scheme == "ifca":
-                losses = jax.vmap(device_losses, in_axes=(None, 0, 0, 0))(
-                    models_, dx, valid, dkeys)          # (N, M)
-                losses = jnp.where(model_valid[None, :] > 0, losses,
-                                   jnp.inf)
-                assign = jnp.argmin(losses, axis=1)
-            elif cfg.scheme == "fesem":
-                # e-step: distance between one-step-updated local params
-                # and each center, in parameter space
-                def dev_assign(x, v, k_, a):
-                    p_cur = jax.tree.map(lambda t: t[a], models_)
-                    g = grad_fn(p_cur, x, v, k_)
-                    upd = jax.tree.map(lambda p_, g_: p_ - cfg.lr * g_,
-                                       p_cur, g)
-                    fu = _flat(upd)
-                    d = jax.vmap(lambda j: jnp.sum(jnp.square(
-                        fu - _flat(jax.tree.map(lambda t: t[j],
-                                                models_)))))(jnp.arange(M))
-                    return jnp.argmin(jnp.where(model_valid > 0, d,
-                                                jnp.inf))
-                assign = jax.vmap(dev_assign)(dx, valid, dkeys, assign)
-            # fedgroup: static
+            with jax.named_scope("assign"):
+                if cfg.scheme == "ifca":
+                    losses = jax.vmap(device_losses,
+                                      in_axes=(None, 0, 0, 0))(
+                        models_, dx, valid, dkeys)          # (N, M)
+                    losses = jnp.where(model_valid[None, :] > 0, losses,
+                                       jnp.inf)
+                    assign = jnp.argmin(losses, axis=1)
+                elif cfg.scheme == "fesem":
+                    # e-step: distance between one-step-updated local
+                    # params and each center, in parameter space
+                    def dev_assign(x, v, k_, a):
+                        p_cur = jax.tree.map(lambda t: t[a], models_)
+                        g = grad_fn(p_cur, x, v, k_)
+                        upd = jax.tree.map(lambda p_, g_: p_ - cfg.lr * g_,
+                                           p_cur, g)
+                        fu = _flat(upd)
+                        d = jax.vmap(lambda j: jnp.sum(jnp.square(
+                            fu - _flat(jax.tree.map(lambda t: t[j],
+                                                    models_)))))(
+                            jnp.arange(M))
+                        return jnp.argmin(jnp.where(model_valid > 0, d,
+                                                    jnp.inf))
+                    assign = jax.vmap(dev_assign)(dx, valid, dkeys, assign)
+                # fedgroup: static
 
             # ---- local grads on the assigned model ----
             def dev_grad(x, v, k_, a):
                 p_cur = jax.tree.map(lambda t: t[a], models_)
                 return grad_fn(p_cur, x, v, k_)
-            gs = jax.vmap(dev_grad)(dx, valid, dkeys, assign)
-            if faulty:
-                # faulty channel lives on the ORIGINAL trace's shadow
-                # device range — _split_trace PADs kind-3 rows out of
-                # both client/server splits, so read ``trace`` directly
-                fscale = trace_faulty_scale(trace, N, epoch)
-                gs = jax.tree.map(
-                    lambda g_: g_ * fscale.reshape(
-                        (-1,) + (1,) * (g_.ndim - 1)), gs)
+            with jax.named_scope("local_grad"):
+                gs = jax.vmap(dev_grad)(dx, valid, dkeys, assign)
+            with jax.named_scope("combine"):
+                if faulty:
+                    # faulty channel lives on the ORIGINAL trace's shadow
+                    # device range — _split_trace PADs kind-3 rows out of
+                    # both client/server splits, so read ``trace``
+                    # directly
+                    fscale = trace_faulty_scale(trace, N, epoch)
+                    gs = jax.tree.map(
+                        lambda g_: g_ * fscale.reshape(
+                            (-1,) + (1,) * (g_.ndim - 1)), gs)
 
-            # ---- per-model weighted aggregation ----
-            onehot = jax.nn.one_hot(assign, M, dtype=jnp.float32)  # (N, M)
-            w = counts * a_dev
-            denom = onehot.T @ w                                   # (M,)
+                # ---- per-model weighted aggregation ----
+                onehot = jax.nn.one_hot(assign, M,
+                                        dtype=jnp.float32)       # (N, M)
+                w = counts * a_dev
+                denom = onehot.T @ w                              # (M,)
 
-            def agg_leaf(gleaf):
-                flatg = gleaf.reshape(N, -1)
-                num = onehot.T @ (flatg * w[:, None])
-                mean = num / jnp.maximum(denom[:, None], 1e-30)
-                return mean.reshape((M,) + gleaf.shape[1:])
-            g_m = jax.tree.map(agg_leaf, gs)
-            upd_gate = ((denom > 0).astype(jnp.float32) * a_grp)
-            models_ = jax.tree.map(
-                lambda p_, g_: p_ - cfg.lr * upd_gate.reshape(
-                    (-1,) + (1,) * (g_.ndim - 1)) * g_,
-                models_, g_m)
+                def agg_leaf(gleaf):
+                    flatg = gleaf.reshape(N, -1)
+                    num = onehot.T @ (flatg * w[:, None])
+                    mean = num / jnp.maximum(denom[:, None], 1e-30)
+                    return mean.reshape((M,) + gleaf.shape[1:])
+                g_m = jax.tree.map(agg_leaf, gs)
+                upd_gate = ((denom > 0).astype(jnp.float32) * a_grp)
+                models_ = jax.tree.map(
+                    lambda p_, g_: p_ - cfg.lr * upd_gate.reshape(
+                        (-1,) + (1,) * (g_.ndim - 1)) * g_,
+                    models_, g_m)
 
-            scores = jax.vmap(
-                lambda p: det.anomaly_scores(p, tx))(models_)
-            # per-sample min over LIVE models only (padded slots hold
-            # untrained inits whose scores must not leak into the loss)
-            tl = jnp.mean(jnp.min(
-                jnp.where(model_valid[:, None] > 0, scores, jnp.inf),
-                axis=0))
+            with jax.named_scope("test_scoring"):
+                scores = jax.vmap(
+                    lambda p: det.anomaly_scores(p, tx))(models_)
+                # per-sample min over LIVE models only (padded slots hold
+                # untrained inits whose scores must not leak into the
+                # loss)
+                tl = jnp.mean(jnp.min(
+                    jnp.where(model_valid[:, None] > 0, scores, jnp.inf),
+                    axis=0))
             return (models_, assign, rkey), tl
 
         (models, assign, _), losses = jax.lax.scan(
             round_fn, (models, assign0, k_train), jnp.arange(cfg.rounds))
-        final_scores = jax.vmap(
-            lambda p: det.anomaly_scores(p, tx))(models)
+        with jax.named_scope("final_scoring"):
+            final_scores = jax.vmap(
+                lambda p: det.anomaly_scores(p, tx))(models)
         return MultiOutputs(losses, final_scores, assign)
 
     return core

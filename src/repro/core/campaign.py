@@ -77,6 +77,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.baselines import MultiModelConfig, _build_multimodel_core
 from repro.core.failure import Failure, FailureTrace
 from repro.core.simulate import SimConfig, _build_core, _build_core_arrays
@@ -501,7 +502,8 @@ def _run_batched(batched_call, bcast_args, mapped, plan: Optional[ExecPlan],
                  aot_resolve=None):
     """Dispatch a stacked scenario batch through ``batched_call`` with
     host-side chunking and batch padding per ``plan``; returns the
-    outputs pytree as numpy arrays with the padding stripped.
+    outputs pytree as numpy arrays with the padding stripped.  Its host
+    work runs in the ``campaign.*`` stage spans (:mod:`repro.obs`).
 
     ``mapped`` is a tuple of pytrees sharing the scenario leading axis —
     (traces, seeds) for a single campaign, plus the stacked per-cell
@@ -523,29 +525,35 @@ def _run_batched(batched_call, bcast_args, mapped, plan: Optional[ExecPlan],
     if b_pad != B:
         # pad by repeating scenario 0 — any valid scenario works, the
         # rows are stripped below before post-processing
-        sel = np.concatenate([np.arange(B), np.zeros(b_pad - B, np.int64)])
-        mapped = jax.tree.map(lambda x: x[sel], mapped)
+        with obs.span("campaign.build"):
+            sel = np.concatenate([np.arange(B),
+                                  np.zeros(b_pad - B, np.int64)])
+            mapped = jax.tree.map(lambda x: x[sel], mapped)
     if aot_resolve is not None:
-        sds = jax.ShapeDtypeStruct
-        avals = tuple(
-            jax.tree.map(lambda x: sds(x.shape, x.dtype), a)
-            for a in bcast_args) + tuple(
-            jax.tree.map(lambda x: sds((chunk,) + x.shape[1:], x.dtype),
-                         m)
-            for m in mapped)
-        batched_call = aot_resolve(avals)
+        with obs.span("campaign.resolve"):
+            sds = jax.ShapeDtypeStruct
+            avals = tuple(
+                jax.tree.map(lambda x: sds(x.shape, x.dtype), a)
+                for a in bcast_args) + tuple(
+                jax.tree.map(
+                    lambda x: sds((chunk,) + x.shape[1:], x.dtype), m)
+                for m in mapped)
+            batched_call = aot_resolve(avals)
     outs = []
     for c in range(n_chunks):
         sl = slice(c * chunk, (c + 1) * chunk)
-        out = batched_call(*bcast_args,
-                           *jax.tree.map(lambda x: x[sl], mapped))
+        with obs.span("campaign.dispatch"):
+            out = batched_call(*bcast_args,
+                               *jax.tree.map(lambda x: x[sl], mapped))
         # materialise on the host per chunk: device memory stays bounded
         # by chunk_size however large the grid is
-        outs.append(jax.tree.map(np.asarray, out))
+        with obs.span("campaign.fetch"):
+            outs.append(jax.tree.map(np.asarray, out))
     if n_chunks == 1 and b_pad == B:
         return outs[0]
-    full = jax.tree.map(lambda *xs: np.concatenate(xs, axis=0), *outs)
-    return jax.tree.map(lambda x: x[:B], full)
+    with obs.span("campaign.post"):
+        full = jax.tree.map(lambda *xs: np.concatenate(xs, axis=0), *outs)
+        return jax.tree.map(lambda x: x[:B], full)
 
 
 def _padded_topology_arrays(topo, k_pad: int):
@@ -557,8 +565,8 @@ def _padded_topology_arrays(topo, k_pad: int):
     heads[:topo.num_clusters] = topo.heads
     head_valid = np.zeros(k_pad, np.float32)
     head_valid[:topo.num_clusters] = 1.0
-    return (jnp.asarray(topo.device_cluster_array()), jnp.asarray(heads),
-            jnp.asarray(head_valid))
+    return (obs.to_device(topo.device_cluster_array()),
+            obs.to_device(heads), obs.to_device(head_valid))
 
 
 def run_campaign(model: ModelLike, device_x: np.ndarray,

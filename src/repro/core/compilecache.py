@@ -26,10 +26,14 @@ invoking XLA.
   WHOLE-EXECUTABLE cache on top of XLA's module cache: the AOT path
   (:func:`repro.core.campaign.aot_executable`) serialises each compiled
   bucket executable (``jax.experimental.serialize_executable``) under
-  ``<cache>/executables/<jax+backend fingerprint>/``, so a warm re-run
-  in a fresh process skips TRACING as well as XLA — XLA's own cache
-  only short-circuits compilation, and for campaign-sized programs the
-  Python trace/lower step costs seconds of its own.
+  ``<cache>/executables/<jax+backend+source fingerprint>/``, so a warm
+  re-run in a fresh process skips TRACING as well as XLA — XLA's own
+  cache only short-circuits compilation, and for campaign-sized
+  programs the Python trace/lower step costs seconds of its own.
+  XLA's module cache leaves op metadata out of its key
+  (``jax_compilation_cache_include_metadata_in_key`` is off), so an
+  executable it serves may carry the ``jax.named_scope`` names of an
+  older build of the same program.
 
 Environment (JAX's own variables):
 
@@ -44,6 +48,7 @@ Environment (JAX's own variables):
 """
 from __future__ import annotations
 
+import functools
 import hashlib
 import os
 import pickle
@@ -171,17 +176,40 @@ def _reset_jax_cache_handle() -> None:
 # ---------------------------------------------------------------------------
 # Whole-executable cache (the layer above XLA's module cache)
 # ---------------------------------------------------------------------------
+#: the package whose sources are digested into the executable-cache tag
+_REPRO_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+@functools.lru_cache(maxsize=1)
+def source_digest() -> str:
+    """SHA-256 (first 16 hex digits) of every ``*.py`` under the
+    ``repro`` package, by sorted relative path: cache entries are keyed
+    by configuration, not by program, so the executable cache is tagged
+    by the code that builds and wraps the cores."""
+    paths = []
+    for root, dirs, files in os.walk(_REPRO_DIR):
+        dirs[:] = [d for d in dirs if d != "__pycache__"]
+        paths += [os.path.join(root, f) for f in files if f.endswith(".py")]
+    h = hashlib.sha256()
+    for rel in sorted(os.path.relpath(p, _REPRO_DIR) for p in paths):
+        with open(os.path.join(_REPRO_DIR, rel), "rb") as f:
+            h.update(rel.encode() + b"\0" + f.read())
+    return h.hexdigest()[:16]
+
+
 def _exe_dir() -> Optional[str]:
     """Executable-cache directory, fingerprinted by the jax/jaxlib
     version and backend — a serialized executable only loads into the
     runtime that produced it, so upgrades silently start a fresh
-    namespace instead of failing deserialisation."""
+    namespace instead of failing deserialisation — and by
+    :func:`source_digest`, so an executable stored before a change to
+    the program's code is never loaded after it."""
     d = _state["dir"]
     if d is None:
         return None
     import jaxlib
     tag = (f"jax-{jax.__version__}-jaxlib-{jaxlib.__version__}"
-           f"-{jax.default_backend()}")
+           f"-{jax.default_backend()}-src-{source_digest()}")
     return os.path.join(d, "executables", tag)
 
 

@@ -55,7 +55,6 @@ Typical use::
 from __future__ import annotations
 
 import dataclasses
-import time
 import warnings
 from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
@@ -65,6 +64,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.configs.autoencoder_paper import AutoencoderConfig
 from repro.core import campaign as _c
 from repro.core import compilecache
@@ -540,7 +540,8 @@ def _geometry(bucket: BucketPlan, exec_plan: Optional[ExecPlan]) -> None:
 
 
 def plan(spec: ExperimentSpec, check: bool = False) -> ExecutionPlan:
-    """Lower a spec to dispatch buckets — pure host-side work.
+    """Lower a spec to dispatch buckets — pure host-side work, inside
+    the span ``campaign.plan``.
 
     Raises ``ValueError`` up front for empty grids, unknown schemes and
     invalid :class:`ExecPlan` values (the legacy paths failed deep
@@ -551,6 +552,11 @@ def plan(spec: ExperimentSpec, check: bool = False) -> ExecutionPlan:
     each bucket's executable (no execution; the trace is shared with a
     later compile) and attaches the report, which ``describe()`` then
     renders as a per-bucket static-analysis section."""
+    with obs.span("campaign.plan"):
+        return _plan(spec, check)
+
+
+def _plan(spec: ExperimentSpec, check: bool) -> ExecutionPlan:
     if not spec.cells:
         raise ValueError("empty experiment: need >= 1 cell")
     if len(spec.seeds.seeds) == 0:
@@ -663,8 +669,15 @@ class BucketCompileStats:
 
     ``lower_s`` / ``compile_s`` are wall times of the AOT lowering and
     XLA compile (0 on the jit path and on in-process AOT cache hits);
-    ``execute_s`` is the bucket's whole dispatch — array builds,
-    executable resolution and the batched call(s).  ``cache`` is
+    ``execute_s`` is the bucket's whole host time, and the five stage
+    times that sum to it (each from its ``campaign.<stage>`` span,
+    :mod:`repro.obs`): ``build_s`` (host arrays, trace stacking,
+    padding and the host-to-device transfers), ``resolve_s`` (waiting
+    on the plan-time compile and resolving the executable),
+    ``dispatch_s`` (the executable calls and chunk slicing),
+    ``fetch_s`` (waiting for the device and copying the outputs back)
+    and ``post_s`` (AUROC post-processing and result slicing).
+    ``cache`` is
     ``""`` (jit path), ``"memory"`` (in-process AOT cache already held
     the executable), ``"disk"`` (deserialised whole from the persistent
     executable cache — no trace, no XLA; ``compile_s`` is the load
@@ -680,6 +693,11 @@ class BucketCompileStats:
     lower_s: float = 0.0
     compile_s: float = 0.0
     execute_s: float = 0.0
+    build_s: float = 0.0
+    resolve_s: float = 0.0
+    dispatch_s: float = 0.0
+    fetch_s: float = 0.0
+    post_s: float = 0.0
     cache: str = ""
     aval_match: Optional[bool] = None
 
@@ -721,7 +739,9 @@ class CompileReport:
                 f"  bucket {b.bucket} ({b.kind}"
                 f"{' fused' if b.fused else ''}): "
                 f"lower={b.lower_s:.3f}s compile={b.compile_s:.3f}s "
-                f"execute={b.execute_s:.3f}s"
+                f"execute={b.execute_s:.3f}s (build={b.build_s:.3f}s "
+                f"resolve={b.resolve_s:.3f}s dispatch={b.dispatch_s:.3f}s "
+                f"fetch={b.fetch_s:.3f}s post={b.post_s:.3f}s)"
                 + (f" cache={b.cache}" if b.cache else "")
                 + (f" aval_match={b.aval_match}"
                    if b.aval_match is not None else ""))
@@ -899,39 +919,43 @@ def _exec_single_cell(data: DataSpec, cfg: SimConfig,
     body): topology closed over statically (``pad_k=None``) or entering
     as broadcast padded arrays (``pad_k=int``)."""
     topo = cfg.topology()
-    norm = [as_trace(t, topo) for t in traces]
-    trace_idx, seed_arr = _c._scenario_grid(len(norm), seeds)
-    if len(trace_idx) == 0:
-        raise ValueError("empty campaign: need >=1 trace and >=1 seed")
-    stacked = stack_traces(norm)
-    batch_traces = jax.tree.map(lambda x: x[trace_idx], stacked)
+    with obs.span("campaign.build"):
+        norm = [as_trace(t, topo) for t in traces]
+        trace_idx, seed_arr = _c._scenario_grid(len(norm), seeds)
+        if len(trace_idx) == 0:
+            raise ValueError("empty campaign: need >=1 trace and >=1 seed")
+        stacked = stack_traces(norm)
+        batch_traces = jax.tree.map(lambda x: x[trace_idx], stacked)
 
-    dx, counts, valid = _prepare_arrays(cfg, data.device_x,
-                                        data.device_counts)
-    tx = jnp.asarray(data.test_x)
-    assert dx.shape[0] == topo.num_devices, (dx.shape, topo.num_devices)
+        dx, counts, valid = _prepare_arrays(cfg, data.device_x,
+                                            data.device_counts)
+        tx = obs.to_device(data.test_x)
+        assert dx.shape[0] == topo.num_devices, (dx.shape,
+                                                 topo.num_devices)
 
-    track_iso = (cfg.scheme == "fl")
-    if pad_k is None:
-        key_cfg = dataclasses.replace(cfg, seed=0)
-        bcast = (dx, counts, valid, tx)
-    else:
-        # scheme / num_clusters are normalised OUT of the cache key: the
-        # padded core reads the topology from the arrays, so every
-        # single-model sweep cell of the same track_iso kind resolves to
-        # the same executable
-        key_cfg = dataclasses.replace(cfg, seed=0, scheme="tolfl",
-                                      num_clusters=1)
-        bcast = (dx, counts, valid, tx) + _c._padded_topology_arrays(
-            topo, pad_k)
-    ndev = exec_plan.resolved_devices(warn=False) if exec_plan else None
-    batched = _c._executable("single", data.model, key_cfg, pad_k, ndev,
-                             track_iso)
-    out = _c._run_batched(batched, bcast,
-                          (batch_traces, jnp.asarray(seed_arr)),
-                          exec_plan, aot_resolve=aot_resolve)
-    return _c._post_process(cfg, out, trace_idx, seed_arr, data.test_y,
-                            target_loss)
+        track_iso = (cfg.scheme == "fl")
+        if pad_k is None:
+            key_cfg = dataclasses.replace(cfg, seed=0)
+            bcast = (dx, counts, valid, tx)
+        else:
+            # scheme / num_clusters are normalised OUT of the cache key:
+            # the padded core reads the topology from the arrays, so
+            # every single-model sweep cell of the same track_iso kind
+            # resolves to the same executable
+            key_cfg = dataclasses.replace(cfg, seed=0, scheme="tolfl",
+                                          num_clusters=1)
+            bcast = (dx, counts, valid, tx) + _c._padded_topology_arrays(
+                topo, pad_k)
+        mapped = (batch_traces, obs.to_device(seed_arr))
+    with obs.span("campaign.resolve"):
+        ndev = exec_plan.resolved_devices(warn=False) if exec_plan else None
+        batched = _c._executable("single", data.model, key_cfg, pad_k,
+                                 ndev, track_iso)
+    out = _c._run_batched(batched, bcast, mapped, exec_plan,
+                          aot_resolve=aot_resolve)
+    with obs.span("campaign.post"):
+        return _c._post_process(cfg, out, trace_idx, seed_arr,
+                                data.test_y, target_loss)
 
 
 def _exec_multi_cell(data: DataSpec, cfg: MultiModelConfig,
@@ -939,32 +963,34 @@ def _exec_multi_cell(data: DataSpec, cfg: MultiModelConfig,
                      exec_plan, aot_resolve=None) -> MultiCampaignResult:
     """One multi-model cell, unfused (the legacy
     ``run_multimodel_campaign`` body)."""
-    norm = [as_multimodel_trace(t, cfg.num_devices) for t in traces]
-    trace_idx, seed_arr = _c._scenario_grid(len(norm), seeds)
-    if len(trace_idx) == 0:
-        raise ValueError("empty campaign: need >=1 trace and >=1 seed")
-    stacked = stack_traces(norm)
-    batch_traces = jax.tree.map(lambda x: x[trace_idx], stacked)
+    with obs.span("campaign.build"):
+        norm = [as_multimodel_trace(t, cfg.num_devices) for t in traces]
+        trace_idx, seed_arr = _c._scenario_grid(len(norm), seeds)
+        if len(trace_idx) == 0:
+            raise ValueError("empty campaign: need >=1 trace and >=1 seed")
+        stacked = stack_traces(norm)
+        batch_traces = jax.tree.map(lambda x: x[trace_idx], stacked)
 
-    dx, counts, valid = prepare_multimodel_arrays(data.device_x,
-                                                  data.device_counts)
-    tx = jnp.asarray(data.test_x)
-    assert dx.shape[0] == cfg.num_devices, (dx.shape, cfg.num_devices)
-    key_cfg = dataclasses.replace(cfg, seed=0)
-    ndev = exec_plan.resolved_devices(warn=False) if exec_plan else None
-    batched = _c._executable("multi", data.model, key_cfg, None, ndev)
-    model_valid = jnp.ones((cfg.num_models,), jnp.float32)
+        dx, counts, valid = prepare_multimodel_arrays(data.device_x,
+                                                      data.device_counts)
+        tx = obs.to_device(data.test_x)
+        assert dx.shape[0] == cfg.num_devices, (dx.shape, cfg.num_devices)
+        model_valid = jnp.ones((cfg.num_models,), jnp.float32)
+        mapped = (batch_traces, obs.to_device(seed_arr))
+    with obs.span("campaign.resolve"):
+        key_cfg = dataclasses.replace(cfg, seed=0)
+        ndev = exec_plan.resolved_devices(warn=False) if exec_plan else None
+        batched = _c._executable("multi", data.model, key_cfg, None, ndev)
     out = _c._run_batched(batched, (dx, counts, valid, tx, model_valid),
-                          (batch_traces, jnp.asarray(seed_arr)),
-                          exec_plan, aot_resolve=aot_resolve)
+                          mapped, exec_plan, aot_resolve=aot_resolve)
 
-    best, multi = _c._multi_metrics(np.asarray(out.final_scores),
-                                    data.test_y)
-    return MultiCampaignResult(cfg=cfg, trace_index=trace_idx,
-                               seed=seed_arr, best_auroc=best,
-                               multi_auroc=multi,
-                               loss_curves=np.asarray(out.losses),
-                               assignments=np.asarray(out.assignments))
+    with obs.span("campaign.post"):
+        best, multi = _c._multi_metrics(np.asarray(out.final_scores),
+                                        data.test_y)
+        return MultiCampaignResult(
+            cfg=cfg, trace_index=trace_idx, seed=seed_arr, best_auroc=best,
+            multi_auroc=multi, loss_curves=np.asarray(out.losses),
+            assignments=np.asarray(out.assignments))
 
 
 def _stacked_scenarios(cells, seeds, trace_cache, trace_key_fn, norm_fn):
@@ -998,11 +1024,6 @@ def _exec_fused_single_group(data: DataSpec, cells, seeds, target_loss,
     group body): every cell's padded cluster arrays stacked as VMAPPED
     operands along the flattened (cell x trace x seed) axis — ONE
     dispatch for the whole bucket."""
-    dx, counts, valid = _prepare_arrays(cells[0][0], data.device_x,
-                                        data.device_counts)
-    tx = jnp.asarray(data.test_x)
-    ndev = exec_plan.resolved_devices(warn=False) if exec_plan else None
-
     def trace_key(cfg, traces):
         return (tuple(id(t) for t in traces),
                 _c._single_trace_key(traces, cfg.topology()))
@@ -1010,36 +1031,44 @@ def _exec_fused_single_group(data: DataSpec, cells, seeds, target_loss,
     def norm(cfg, traces):
         return [as_trace(t, cfg.topology()) for t in traces]
 
-    metas = _stacked_scenarios(cells, seeds, trace_cache, trace_key, norm)
-    cids_l, heads_l, hv_l, tr_l, seeds_l = [], [], [], [], []
-    for cfg, batch_traces, trace_idx, seed_arr, b in metas:
-        topo = cfg.topology()
-        assert dx.shape[0] == topo.num_devices, (dx.shape,
-                                                 topo.num_devices)
-        cids, heads, hvalid = _c._padded_topology_arrays(topo, kp)
-        cids_l.append(jnp.broadcast_to(cids, (b,) + cids.shape))
-        heads_l.append(jnp.broadcast_to(heads, (b,) + heads.shape))
-        hv_l.append(jnp.broadcast_to(hvalid, (b,) + hvalid.shape))
-        tr_l.append(batch_traces)
-        seeds_l.append(seed_arr)
+    with obs.span("campaign.build"):
+        dx, counts, valid = _prepare_arrays(cells[0][0], data.device_x,
+                                            data.device_counts)
+        tx = obs.to_device(data.test_x)
+        metas = _stacked_scenarios(cells, seeds, trace_cache, trace_key,
+                                   norm)
+        cids_l, heads_l, hv_l, tr_l, seeds_l = [], [], [], [], []
+        for cfg, batch_traces, trace_idx, seed_arr, b in metas:
+            topo = cfg.topology()
+            assert dx.shape[0] == topo.num_devices, (dx.shape,
+                                                     topo.num_devices)
+            cids, heads, hvalid = _c._padded_topology_arrays(topo, kp)
+            cids_l.append(jnp.broadcast_to(cids, (b,) + cids.shape))
+            heads_l.append(jnp.broadcast_to(heads, (b,) + heads.shape))
+            hv_l.append(jnp.broadcast_to(hvalid, (b,) + hvalid.shape))
+            tr_l.append(batch_traces)
+            seeds_l.append(seed_arr)
 
-    mapped = (jnp.concatenate(cids_l), jnp.concatenate(heads_l),
-              jnp.concatenate(hv_l), concat_traces(tr_l),
-              jnp.asarray(np.concatenate(seeds_l)))
-    batched = _c._executable("single", data.model, key_cfg, kp, ndev,
-                             track_iso, fused=True)
+        mapped = (jnp.concatenate(cids_l), jnp.concatenate(heads_l),
+                  jnp.concatenate(hv_l), concat_traces(tr_l),
+                  obs.to_device(np.concatenate(seeds_l)))
+    with obs.span("campaign.resolve"):
+        ndev = exec_plan.resolved_devices(warn=False) if exec_plan else None
+        batched = _c._executable("single", data.model, key_cfg, kp, ndev,
+                                 track_iso, fused=True)
     out = _c._run_batched(batched, (dx, counts, valid, tx), mapped,
                           exec_plan, aot_resolve=aot_resolve)
-    fields = _c._post_process_arrays(track_iso, out, data.test_y,
-                                     target_loss)
-    results, off = [], 0
-    for cfg, _, trace_idx, seed_arr, b in metas:
-        cell_fields = {name: arr[off:off + b]
-                       for name, arr in fields.items()}
-        results.append(CampaignResult(cfg=cfg, trace_index=trace_idx,
-                                      seed=seed_arr, **cell_fields))
-        off += b
-    return results
+    with obs.span("campaign.post"):
+        fields = _c._post_process_arrays(track_iso, out, data.test_y,
+                                         target_loss)
+        results, off = [], 0
+        for cfg, _, trace_idx, seed_arr, b in metas:
+            cell_fields = {name: arr[off:off + b]
+                           for name, arr in fields.items()}
+            results.append(CampaignResult(cfg=cfg, trace_index=trace_idx,
+                                          seed=seed_arr, **cell_fields))
+            off += b
+        return results
 
 
 def _exec_fused_multi_group(data: DataSpec, cells, seeds, exec_plan,
@@ -1049,50 +1078,53 @@ def _exec_fused_multi_group(data: DataSpec, cells, seeds, exec_plan,
     ``run_fused_multimodel_campaigns`` group body): cells with
     DIFFERENT model counts share one executable via the padded-M
     ``model_valid`` mask."""
-    dx, counts, valid = prepare_multimodel_arrays(data.device_x,
-                                                  data.device_counts)
-    tx = jnp.asarray(data.test_x)
-    ndev = exec_plan.resolved_devices(warn=False) if exec_plan else None
-
     def trace_key(cfg, traces):
         return (tuple(id(t) for t in traces), cfg.num_devices)
 
     def norm(cfg, traces):
         return [as_multimodel_trace(t, cfg.num_devices) for t in traces]
 
-    metas = _stacked_scenarios(cells, seeds, trace_cache, trace_key, norm)
-    mv_l, tr_l, seeds_l = [], [], []
-    for cfg, batch_traces, trace_idx, seed_arr, b in metas:
-        assert dx.shape[0] == cfg.num_devices, (dx.shape,
-                                                cfg.num_devices)
-        assert mp >= cfg.num_models, (mp, cfg.num_models)
-        mv = np.zeros((mp,), np.float32)
-        mv[:cfg.num_models] = 1.0
-        mv_l.append(jnp.broadcast_to(jnp.asarray(mv), (b, mp)))
-        tr_l.append(batch_traces)
-        seeds_l.append(seed_arr)
+    with obs.span("campaign.build"):
+        dx, counts, valid = prepare_multimodel_arrays(data.device_x,
+                                                      data.device_counts)
+        tx = obs.to_device(data.test_x)
+        metas = _stacked_scenarios(cells, seeds, trace_cache, trace_key,
+                                   norm)
+        mv_l, tr_l, seeds_l = [], [], []
+        for cfg, batch_traces, trace_idx, seed_arr, b in metas:
+            assert dx.shape[0] == cfg.num_devices, (dx.shape,
+                                                    cfg.num_devices)
+            assert mp >= cfg.num_models, (mp, cfg.num_models)
+            mv = np.zeros((mp,), np.float32)
+            mv[:cfg.num_models] = 1.0
+            mv_l.append(jnp.broadcast_to(obs.to_device(mv), (b, mp)))
+            tr_l.append(batch_traces)
+            seeds_l.append(seed_arr)
 
-    mapped = (jnp.concatenate(mv_l), concat_traces(tr_l),
-              jnp.asarray(np.concatenate(seeds_l)))
-    exe_cfg = dataclasses.replace(key_cfg, num_models=mp)
-    batched = _c._executable("multi", data.model, exe_cfg, None, ndev,
-                             fused=True)
+        mapped = (jnp.concatenate(mv_l), concat_traces(tr_l),
+                  obs.to_device(np.concatenate(seeds_l)))
+    with obs.span("campaign.resolve"):
+        ndev = exec_plan.resolved_devices(warn=False) if exec_plan else None
+        exe_cfg = dataclasses.replace(key_cfg, num_models=mp)
+        batched = _c._executable("multi", data.model, exe_cfg, None, ndev,
+                                 fused=True)
     out = _c._run_batched(batched, (dx, counts, valid, tx), mapped,
                           exec_plan, aot_resolve=aot_resolve)
-    model_valid = np.asarray(mapped[0])
-    best, multi = _c._multi_metrics(np.asarray(out.final_scores),
-                                    data.test_y, model_valid)
-    losses = np.asarray(out.losses)
-    assigns = np.asarray(out.assignments)
-    results, off = [], 0
-    for cfg, _, trace_idx, seed_arr, b in metas:
-        sl = slice(off, off + b)
-        results.append(MultiCampaignResult(
-            cfg=cfg, trace_index=trace_idx, seed=seed_arr,
-            best_auroc=best[sl], multi_auroc=multi[sl],
-            loss_curves=losses[sl], assignments=assigns[sl]))
-        off += b
-    return results
+    with obs.span("campaign.post"):
+        model_valid = np.asarray(mapped[0])
+        best, multi = _c._multi_metrics(np.asarray(out.final_scores),
+                                        data.test_y, model_valid)
+        losses = np.asarray(out.losses)
+        assigns = np.asarray(out.assignments)
+        results, off = [], 0
+        for cfg, _, trace_idx, seed_arr, b in metas:
+            sl = slice(off, off + b)
+            results.append(MultiCampaignResult(
+                cfg=cfg, trace_index=trace_idx, seed=seed_arr,
+                best_auroc=best[sl], multi_auroc=multi[sl],
+                loss_curves=losses[sl], assignments=assigns[sl]))
+            off += b
+        return results
 
 
 def _make_resolver(stats: BucketCompileStats, key_args: tuple, future):
@@ -1130,7 +1162,8 @@ def execute(plan_: ExecutionPlan) -> ExperimentResult:
     ``plan()`` already knows each bucket's shapes
     (:func:`_bucket_avals`), so XLA overlaps the host-side data/trace
     array builds and the buckets dispatch through compiled executables.
-    Either path attaches a :class:`CompileReport`."""
+    Either path attaches a :class:`CompileReport` and appends one record
+    to :func:`repro.obs.executions`."""
     spec = plan_.spec
     data, seeds = spec.data, spec.seeds.seeds
     exec_plan, target_loss = spec.exec_plan, spec.target_loss
@@ -1162,36 +1195,41 @@ def execute(plan_: ExecutionPlan) -> ExperimentResult:
     results: List[Optional[Any]] = [None] * len(plan_.cells)
     trace_cache: dict = {}   # one stacked batch per distinct resolution
     try:
-        for bucket in plan_.buckets:
-            cells = [plan_.cells[i] for i in bucket.cell_indices]
-            pairs = [(c.cfg, c.traces) for c in cells]
-            resolve = (_make_resolver(stats[bucket.index],
-                                      _bucket_exe_args(data, bucket),
-                                      futures[bucket.index])
-                       if use_aot else None)
-            t0 = time.perf_counter()
-            if bucket.kind == "single" and bucket.fused:
-                rs = _exec_fused_single_group(
-                    data, pairs, seeds, target_loss, exec_plan,
-                    bucket.k_pad, bucket.key_cfg, bucket.track_iso,
-                    trace_cache, aot_resolve=resolve)
-            elif bucket.kind == "multi" and bucket.fused:
-                rs = _exec_fused_multi_group(
-                    data, pairs, seeds, exec_plan, bucket.m_pad,
-                    bucket.key_cfg, trace_cache, aot_resolve=resolve)
-            elif bucket.kind == "single":
-                rs = [_exec_single_cell(data, cells[0].cfg,
-                                        cells[0].traces, seeds,
-                                        target_loss, exec_plan,
-                                        bucket.k_pad,
-                                        aot_resolve=resolve)]
-            else:
-                rs = [_exec_multi_cell(data, cells[0].cfg,
-                                       cells[0].traces, seeds, exec_plan,
-                                       aot_resolve=resolve)]
-            stats[bucket.index].execute_s = time.perf_counter() - t0
-            for c, r in zip(cells, rs):
-                results[c.index] = r
+        with obs.Tally() as whole:
+            for bucket in plan_.buckets:
+                cells = [plan_.cells[i] for i in bucket.cell_indices]
+                pairs = [(c.cfg, c.traces) for c in cells]
+                st = stats[bucket.index]
+                resolve = (_make_resolver(st, _bucket_exe_args(data, bucket),
+                                          futures[bucket.index])
+                           if use_aot else None)
+                with obs.Tally() as tally:
+                    if bucket.kind == "single" and bucket.fused:
+                        rs = _exec_fused_single_group(
+                            data, pairs, seeds, target_loss, exec_plan,
+                            bucket.k_pad, bucket.key_cfg, bucket.track_iso,
+                            trace_cache, aot_resolve=resolve)
+                    elif bucket.kind == "multi" and bucket.fused:
+                        rs = _exec_fused_multi_group(
+                            data, pairs, seeds, exec_plan, bucket.m_pad,
+                            bucket.key_cfg, trace_cache, aot_resolve=resolve)
+                    elif bucket.kind == "single":
+                        rs = [_exec_single_cell(data, cells[0].cfg,
+                                                cells[0].traces, seeds,
+                                                target_loss, exec_plan,
+                                                bucket.k_pad,
+                                                aot_resolve=resolve)]
+                    else:
+                        rs = [_exec_multi_cell(data, cells[0].cfg,
+                                               cells[0].traces, seeds,
+                                               exec_plan,
+                                               aot_resolve=resolve)]
+                st.execute_s = tally.end - tally.start
+                for name in obs.CAMPAIGN_STAGES:
+                    setattr(st, name.split(".", 1)[1] + "_s",
+                            tally.seconds[name])
+                for c, r in zip(cells, rs):
+                    results[c.index] = r
     finally:
         if pool is not None:
             pool.shutdown(wait=True)
@@ -1201,6 +1239,7 @@ def execute(plan_: ExecutionPlan) -> ExperimentResult:
         aot=use_aot, buckets=stats, traces=_c.TRACE_COUNT - traces0,
         xla={k: xla1[k] - xla0[k] for k in xla1},
         cache_dir=compilecache.persistent_cache_dir())
+    obs.record_execution(whole, scenarios=plan_.num_scenarios)
     return ExperimentResult(plan=plan_, results=results,
                             compile_report=report)
 

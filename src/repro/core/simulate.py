@@ -40,6 +40,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import aggregation as agg
 from repro.core.failure import (Failure, FailureTrace, NO_FAILURE, as_trace,
                                 effective_weights_arrays, trace_alive_mask,
@@ -186,81 +187,96 @@ def _build_core_arrays(model: ModelLike, cfg: SimConfig,
             return jnp.max(jnp.where(head_valid > 0, alive[heads], 0.0))
 
         def round_fn(carry, epoch):
+            # each stage under a jax.named_scope (obs.SINGLE_SCOPES):
+            # HLO op metadata only, the program is unchanged
             params, iso_params, rkey = carry
-            rkey, dkey = jax.random.split(rkey)
-            alive = trace_alive_mask(trace, N, epoch)
-            w = effective_weights_arrays(alive, cluster_ids, heads)
-            dkeys = jax.random.split(dkey, N)
-            head_dead = 1.0 - heads_alive_max(alive)     # all heads dead
-            if track_iso:
-                # One N-way delta vmap serves BOTH the global combine and
-                # the isolated fallback.  Exactness: while any head is
-                # alive, ``iso_params`` rows all equal ``params`` (the
-                # where-reset below), so these ARE the global-path
-                # gradients; on all-heads-dead rounds the values differ
-                # but every effective weight is zero, so the combine is
-                # gated off (``has_update == 0``) and the difference
-                # never reaches ``new_params``.  This halves the
-                # per-round gradient work of iso-tracking cores.
-                iso_params = jax.tree.map(
-                    lambda ip, p_: jnp.where(head_dead > 0, ip,
-                                             jnp.broadcast_to(p_, ip.shape)),
-                    iso_params, params)
-                gs = jax.vmap(delta_fn, in_axes=(0, 0, 0, 0))(
-                    iso_params, dx, valid, dkeys)
-            else:
-                gs = jax.vmap(delta_fn, in_axes=(None, 0, 0, 0))(
-                    params, dx, valid, dkeys)
-            gs_tx = gs
-            if faulty:
-                # corrupt the TRANSMITTED deltas only: the isolated
-                # fallback below keeps the clean ``gs`` (faulty devices
-                # train fine locally, they just send garbage)
-                fscale = trace_faulty_scale(trace, N, epoch)
-                gs_tx = jax.tree.map(
-                    lambda g_: g_ * fscale.reshape(
-                        (-1,) + (1,) * (g_.ndim - 1)), gs)
-            ns = counts * w
-            # ---- Tol-FL hierarchical combine (Algorithm 1) ----
-            cluster_gs, n_c = agg.cluster_reduce(gs_tx, ns, cluster_ids, k)
-            if cfg.combine == "streaming":
-                n_tot, g = agg.stacked_streaming_mean(cluster_gs, n_c)
-            else:
-                g = agg.weighted_mean(cluster_gs, n_c)
-                n_tot = jnp.sum(n_c)
-            has_update = (n_tot > 0).astype(jnp.float32)
-            new_params = jax.tree.map(
-                lambda p_, g_: p_ - cfg.lr * has_update * g_, params, g)
+            with jax.named_scope("local_delta"):
+                rkey, dkey = jax.random.split(rkey)
+            with jax.named_scope("alive_mask"):
+                alive = trace_alive_mask(trace, N, epoch)
+                w = effective_weights_arrays(alive, cluster_ids, heads)
+            with jax.named_scope("local_delta"):
+                dkeys = jax.random.split(dkey, N)
+            with jax.named_scope("alive_mask"):
+                head_dead = 1.0 - heads_alive_max(alive)  # all heads dead
+            with jax.named_scope("local_delta"):
+                if track_iso:
+                    # One N-way delta vmap serves BOTH the global combine
+                    # and the isolated fallback.  Exactness: while any
+                    # head is alive, ``iso_params`` rows all equal
+                    # ``params`` (the where-reset below), so these ARE
+                    # the global-path gradients; on all-heads-dead rounds
+                    # the values differ but every effective weight is
+                    # zero, so the combine is gated off
+                    # (``has_update == 0``) and the difference never
+                    # reaches ``new_params``.  This halves the per-round
+                    # gradient work of iso-tracking cores.
+                    iso_params = jax.tree.map(
+                        lambda ip, p_: jnp.where(
+                            head_dead > 0, ip,
+                            jnp.broadcast_to(p_, ip.shape)),
+                        iso_params, params)
+                    gs = jax.vmap(delta_fn, in_axes=(0, 0, 0, 0))(
+                        iso_params, dx, valid, dkeys)
+                else:
+                    gs = jax.vmap(delta_fn, in_axes=(None, 0, 0, 0))(
+                        params, dx, valid, dkeys)
+            with jax.named_scope("combine"):
+                gs_tx = gs
+                if faulty:
+                    # corrupt the TRANSMITTED deltas only: the isolated
+                    # fallback below keeps the clean ``gs`` (faulty
+                    # devices train fine locally, they just send garbage)
+                    fscale = trace_faulty_scale(trace, N, epoch)
+                    gs_tx = jax.tree.map(
+                        lambda g_: g_ * fscale.reshape(
+                            (-1,) + (1,) * (g_.ndim - 1)), gs)
+                ns = counts * w
+                # ---- Tol-FL hierarchical combine (Algorithm 1) ----
+                cluster_gs, n_c = agg.cluster_reduce(gs_tx, ns,
+                                                     cluster_ids, k)
+                if cfg.combine == "streaming":
+                    n_tot, g = agg.stacked_streaming_mean(cluster_gs, n_c)
+                else:
+                    g = agg.weighted_mean(cluster_gs, n_c)
+                    n_tot = jnp.sum(n_c)
+                has_update = (n_tot > 0).astype(jnp.float32)
+                new_params = jax.tree.map(
+                    lambda p_, g_: p_ - cfg.lr * has_update * g_, params,
+                    g)
 
             # ---- isolated fallback (fl server failure) ----
             if track_iso:
                 # ``iso_params`` already track the global model until
                 # failure (the where-reset above) and ``gs`` holds the
                 # per-device gradients at exactly those params
-                iso_step = head_dead * alive    # only alive devices train
-                iso_params = jax.tree.map(
-                    lambda ip, g_: ip - cfg.lr * iso_step.reshape(
-                        (-1,) + (1,) * (g_.ndim - 1)) * g_,
-                    iso_params, gs)
+                with jax.named_scope("iso_fallback"):
+                    iso_step = head_dead * alive  # only alive devices train
+                    iso_params = jax.tree.map(
+                        lambda ip, g_: ip - cfg.lr * iso_step.reshape(
+                            (-1,) + (1,) * (g_.ndim - 1)) * g_,
+                        iso_params, gs)
                 # Fig 4 reporting averages the surviving devices only:
                 # weight each device's test loss by its alive mask (the
                 # dead server keeps a frozen model and is excluded)
-                per_dev_tl = jax.vmap(test_loss)(iso_params)
-                iso_tl = (jnp.sum(alive * per_dev_tl)
-                          / jnp.maximum(jnp.sum(alive), 1.0))
+                with jax.named_scope("test_scoring"):
+                    per_dev_tl = jax.vmap(test_loss)(iso_params)
+                    iso_tl = (jnp.sum(alive * per_dev_tl)
+                              / jnp.maximum(jnp.sum(alive), 1.0))
             else:
                 iso_tl = jnp.float32(0)
 
-            tl = test_loss(new_params)
-            if score_history:
-                scores = det.anomaly_scores(new_params, tx)
-            else:
-                scores = jnp.zeros((0,), jnp.float32)
-            if track_iso and score_history:
-                iso_scores = jax.vmap(
-                    lambda p: det.anomaly_scores(p, tx))(iso_params)
-            else:
-                iso_scores = jnp.zeros((0, 0), jnp.float32)
+            with jax.named_scope("test_scoring"):
+                tl = test_loss(new_params)
+                if score_history:
+                    scores = det.anomaly_scores(new_params, tx)
+                else:
+                    scores = jnp.zeros((0,), jnp.float32)
+                if track_iso and score_history:
+                    iso_scores = jax.vmap(
+                        lambda p: det.anomaly_scores(p, tx))(iso_params)
+                else:
+                    iso_scores = jnp.zeros((0, 0), jnp.float32)
             return (new_params, iso_params, rkey), (tl, scores, iso_tl,
                                                     iso_scores, head_dead)
 
@@ -272,14 +288,16 @@ def _build_core_arrays(model: ModelLike, cfg: SimConfig,
             jax.lax.scan(round_fn, (params, iso0, key),
                          jnp.arange(cfg.rounds))
 
-        final_alive = trace_alive_mask(trace, N, jnp.int32(cfg.rounds - 1))
-        server_dead = 1.0 - heads_alive_max(final_alive)
-        final_scores = det.anomaly_scores(final_params, tx)
-        if track_iso:
-            iso_final_scores = jax.vmap(
-                lambda p: det.anomaly_scores(p, tx))(iso_params)
-        else:
-            iso_final_scores = jnp.zeros((N, 0), jnp.float32)
+        with jax.named_scope("final_scoring"):
+            final_alive = trace_alive_mask(trace, N,
+                                           jnp.int32(cfg.rounds - 1))
+            server_dead = 1.0 - heads_alive_max(final_alive)
+            final_scores = det.anomaly_scores(final_params, tx)
+            if track_iso:
+                iso_final_scores = jax.vmap(
+                    lambda p: det.anomaly_scores(p, tx))(iso_params)
+            else:
+                iso_final_scores = jnp.zeros((N, 0), jnp.float32)
         outputs = SimOutputs(losses, iso_losses, final_scores,
                              iso_final_scores, final_alive, server_dead,
                              dead_rounds, score_hist, iso_score_hist)
@@ -344,8 +362,8 @@ def _prepare_arrays(cfg: SimConfig, device_x: np.ndarray,
                                for i in range(len(device_counts))], 0)
         device_x = flat[None]
         device_counts = np.array([len(flat)])
-    dx = jnp.asarray(device_x)
-    counts = jnp.asarray(device_counts, jnp.float32)
+    dx = obs.to_device(device_x)
+    counts = obs.to_device(device_counts, jnp.float32)
     valid = (jnp.arange(device_x.shape[1])[None, :]
              < counts[:, None]).astype(jnp.float32)     # (N, n_max)
     return dx, counts, valid

@@ -59,8 +59,9 @@ def _build_score_core(model: ModelLike):
     det = D.as_detector(model)
 
     def score(row_params, row, x):
-        rows = jax.tree.map(lambda p: p[row], row_params)
-        return jax.vmap(det.anomaly_scores, in_axes=(None, 0))(rows, x)
+        with jax.named_scope("score_bucket"):    # obs.SERVE_SCOPES
+            rows = jax.tree.map(lambda p: p[row], row_params)
+            return jax.vmap(det.anomaly_scores, in_axes=(None, 0))(rows, x)
 
     return score
 

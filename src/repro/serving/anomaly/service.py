@@ -33,10 +33,11 @@ Mechanics, SHARK-Engine ``BatchGenerateService`` style:
   inside the compiled core, so failover scores are bit-identical to
   scoring the isolated model directly.
 
-:meth:`AnomalyService.report` summarises a served stream: sustained
-windows/sec, p50/p99 latency, failover/failback counts and — when
+:meth:`AnomalyService.report` summarises a served stream: windows and
+dispatches, p50/p99 latency, failover/failback counts and — when
 submissions carry labels — per-regime AUROC (windows served by a head
-vs. windows served by an isolated fallback).
+vs. windows served by an isolated fallback).  Each tick's host stages
+run in the ``serve.*`` spans of :mod:`repro.obs`.
 """
 from __future__ import annotations
 
@@ -49,6 +50,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.failure import Failure, NO_FAILURE, PAD_EPOCH, as_trace
 from repro.core.processes import FailureProcess, process_seed
 from repro.serving.anomaly import engine
@@ -88,7 +90,6 @@ class ServiceReport:
     windows: int             # windows scored
     dropped: int             # submitted but never scored (always 0)
     batches: int             # compiled-bucket dispatches
-    windows_per_s: float     # sustained: windows / busy wall
     p50_ms: float            # per-window submit->scored latency
     p99_ms: float
     failovers: int           # head->isolated transitions
@@ -99,8 +100,7 @@ class ServiceReport:
 
     def describe(self) -> str:
         return (f"{self.windows} windows in {self.batches} batches "
-                f"({self.windows_per_s:.0f} win/s, p50 "
-                f"{self.p50_ms:.2f} ms, p99 {self.p99_ms:.2f} ms), "
+                f"(p50 {self.p50_ms:.2f} ms, p99 {self.p99_ms:.2f} ms), "
                 f"{self.failovers} failovers / {self.failbacks} "
                 f"failbacks, AUROC head={self.auroc_head:.3f} "
                 f"isolated={self.auroc_isolated:.3f}, "
@@ -165,7 +165,6 @@ class AnomalyService:
         self._bucket_batches: Dict[int, int] = {b: 0 for b in self._buckets}
         self._failovers = 0
         self._failbacks = 0
-        self._busy_s = 0.0
         self._latencies: List[float] = []
         self._regime_scores: Dict[str, list] = {"head": [], "isolated": []}
         self._regime_labels: Dict[str, list] = {"head": [], "isolated": []}
@@ -234,10 +233,20 @@ class AnomalyService:
         through the smallest bucket that fits (remainder rows padded
         with zero windows — padding is sliced off before results are
         reassembled in submission order)."""
-        alive = self.alive_mask()
-        out: List[ScoredWindow] = []
-        while self._pending:
-            t0 = time.perf_counter()
+        with obs.span("serve.tick"):
+            alive = self.alive_mask()
+            out: List[ScoredWindow] = []
+            while self._pending:
+                self._tick_chunk(alive, out)
+            self.epoch += 1
+        return out
+
+    def _tick_chunk(self, alive: np.ndarray, out: List[ScoredWindow]
+                    ) -> None:
+        """Score one chunk of at most the largest bucket, one span per
+        stage (:data:`repro.obs.SERVE_STAGES`): every group's bucket is
+        packed, then all are dispatched, then all fetched."""
+        with obs.span("serve.group"):
             n = min(len(self._pending), self._buckets[-1])
             chunk = [self._pending.popleft() for _ in range(n)]
             modes: List[str] = []
@@ -248,20 +257,28 @@ class AnomalyService:
                 modes.append("isolated" if failover else "head")
                 row = self.bank.row_index(r.client, failover)
                 groups.setdefault(row, []).append(i)
-            scores = np.empty((n, self.config.window), np.float32)
+        with obs.span("serve.pack"):
+            packed = []
             for row, members in groups.items():
                 bs = self._pick_bucket(len(members))
                 x = np.zeros((bs, self.config.window,
                               self.bank.input_dim), np.float32)
                 for j, i in enumerate(members):
                     x[j] = chunk[i].window
-                got = np.asarray(self._compiled[bs](
-                    self.bank.row_params, np.int32(row), x))
-                scores[np.asarray(members)] = got[:len(members)]
+                packed.append((bs, row, members, x))
+        with obs.span("serve.dispatch"):
+            pending = [self._compiled[bs](self.bank.row_params,
+                                          np.int32(row), x)
+                       for bs, row, _, x in packed]
+        with obs.span("serve.fetch"):
+            got = [np.asarray(g) for g in pending]
+        with obs.span("serve.reassemble"):
+            scores = np.empty((n, self.config.window), np.float32)
+            for (bs, _, members, _), g in zip(packed, got):
+                scores[np.asarray(members)] = g[:len(members)]
                 self._batches += 1
                 self._bucket_batches[bs] += 1
             t1 = time.perf_counter()
-            self._busy_s += t1 - t0
             for i, (r, mode) in enumerate(zip(chunk, modes)):
                 prev = self._mode.get(r.client, "head")
                 if mode != prev:
@@ -281,8 +298,6 @@ class AnomalyService:
                     self._regime_labels[mode].append(r.labels)
                 out.append(ScoredWindow(r.client, r.seq, self.epoch,
                                         scores[i], mode, t1 - r.t_submit))
-        self.epoch += 1
-        return out
 
     def run(self, epochs: int) -> List[ScoredWindow]:
         """Tick ``epochs`` times (anything queued between ticks by the
@@ -311,8 +326,6 @@ class AnomalyService:
             windows=self._scored,
             dropped=self._submitted - self._scored - len(self._pending),
             batches=self._batches,
-            windows_per_s=(self._scored / self._busy_s
-                           if self._busy_s > 0 else 0.0),
             p50_ms=float(np.percentile(lat, 50)),
             p99_ms=float(np.percentile(lat, 99)),
             failovers=self._failovers,
