@@ -1151,6 +1151,18 @@ def _make_resolver(stats: BucketCompileStats, key_args: tuple, future):
     return resolve
 
 
+def _backend() -> str:
+    """The platform the bucket cores are lowered for."""
+    return jax.default_backend()
+
+
+def _fuses_output_layer(model: ModelLike) -> bool:
+    """Whether a bucket core of ``model`` runs the fused output-layer
+    kernel here: the model takes it, and the cores are lowered for a
+    TPU."""
+    return as_detector(model).fuses_output_layer() and _backend() == "tpu"
+
+
 def execute(plan_: ExecutionPlan) -> ExperimentResult:
     """Run every bucket of a lowered plan; results align with
     ``plan_.cells`` (and with the spec's cell order).
@@ -1163,7 +1175,8 @@ def execute(plan_: ExecutionPlan) -> ExperimentResult:
     (:func:`_bucket_avals`), so XLA overlaps the host-side data/trace
     array builds and the buckets dispatch through compiled executables.
     Either path attaches a :class:`CompileReport` and appends one record
-    to :func:`repro.obs.executions`."""
+    to :func:`repro.obs.executions`, which counts the buckets that run
+    the fused output-layer kernel (``fused_out_buckets``)."""
     spec = plan_.spec
     data, seeds = spec.data, spec.seeds.seeds
     exec_plan, target_loss = spec.exec_plan, spec.target_loss
@@ -1179,6 +1192,7 @@ def execute(plan_: ExecutionPlan) -> ExperimentResult:
              for b in plan_.buckets]
     traces0 = _c.TRACE_COUNT
     xla0 = compilecache.xla_compile_stats()
+    fused_out = float(_fuses_output_layer(data.model))
 
     pool = futures = None
     if use_aot:
@@ -1204,6 +1218,7 @@ def execute(plan_: ExecutionPlan) -> ExperimentResult:
                                           futures[bucket.index])
                            if use_aot else None)
                 with obs.Tally() as tally:
+                    obs.count(obs.FUSED_OUT_BUCKETS, fused_out)
                     if bucket.kind == "single" and bucket.fused:
                         rs = _exec_fused_single_group(
                             data, pairs, seeds, target_loss, exec_plan,

@@ -72,3 +72,11 @@ def tolfl_combine_reference(gs, ns) -> jax.Array:
         g = (1 - r) * g + r * gs[i]
         n = n_new
     return g
+
+
+def recon_out_reference(h, w, b, x, valid) -> jax.Array:
+    """The autoencoder's output layer and masked reconstruction loss:
+    ``sum(||x - (h w + b)||^2 * valid) / max(sum(valid), 1)``."""
+    x_hat = h @ w + b
+    err = jnp.sum(jnp.square(x - x_hat), axis=-1) * valid
+    return jnp.sum(err) / jnp.maximum(jnp.sum(valid), 1.0)

@@ -36,17 +36,22 @@ def forward(params: P.Params, cfg: AutoencoderConfig, x: jax.Array,
 
     Pass ``dropout_key`` during training to enable dropout on hidden
     layers (paper: p=0.2)."""
+    return P.dense_apply(params[f"fc{num_layers(cfg) - 1}"],
+                         hidden(params, cfg, x, dropout_key))
+
+
+def hidden(params: P.Params, cfg: AutoencoderConfig, x: jax.Array,
+           dropout_key: Optional[jax.Array] = None) -> jax.Array:
+    """x: (B, input_dim) -> the last hidden layer's output (B, hidden[0]),
+    the input of the linear output layer."""
     act = P.activation(cfg.act)
-    n = num_layers(cfg)
     h = x
-    for i in range(n):
-        h = P.dense_apply(params[f"fc{i}"], h)
-        if i < n - 1:                      # hidden layers
-            h = act(h)
-            if dropout_key is not None and cfg.dropout > 0:
-                dk = jax.random.fold_in(dropout_key, i)
-                keep = jax.random.bernoulli(dk, 1.0 - cfg.dropout, h.shape)
-                h = jnp.where(keep, h / (1.0 - cfg.dropout), 0.0)
+    for i in range(num_layers(cfg) - 1):
+        h = act(P.dense_apply(params[f"fc{i}"], h))
+        if dropout_key is not None and cfg.dropout > 0:
+            dk = jax.random.fold_in(dropout_key, i)
+            keep = jax.random.bernoulli(dk, 1.0 - cfg.dropout, h.shape)
+            h = jnp.where(keep, h / (1.0 - cfg.dropout), 0.0)
     return h
 
 

@@ -41,6 +41,7 @@ import jax.numpy as jnp
 
 from repro.configs.autoencoder_paper import AutoencoderConfig, CONFIG
 from repro.configs.base import ModelConfig, RecurrentConfig
+from repro.kernels import ops
 from repro.models import autoencoder as AE
 from repro.models import params as P
 from repro.models import rglru as R
@@ -69,6 +70,11 @@ class DetectorModel:
 
     def anomaly_scores(self, params: P.Params, x: jax.Array) -> jax.Array:
         raise NotImplementedError
+
+    def fuses_output_layer(self) -> bool:
+        """Whether ``loss``, lowered for a TPU, runs its output layer as
+        the fused kernel :func:`repro.kernels.ops.recon_out_layer`."""
+        return False
 
     # ---- derived sizes (comm-cost models / benches) ----
     def param_count(self) -> int:
@@ -101,12 +107,21 @@ class AutoencoderDetector(DetectorModel):
         return params
 
     def loss(self, params, x, valid, key=None):
+        if self.fuses_output_layer():
+            out = params[f"fc{AE.num_layers(self.cfg) - 1}"]
+            return ops.recon_out_layer(
+                AE.hidden(params, self.cfg, x, dropout_key=key),
+                out["w"], out["b"], x, valid)
         x_hat = AE.forward(params, self.cfg, x, dropout_key=key)
         err = jnp.sum(jnp.square(x - x_hat), axis=-1) * valid
         return jnp.sum(err) / jnp.maximum(jnp.sum(valid), 1.0)
 
     def anomaly_scores(self, params, x):
         return AE.anomaly_scores(params, self.cfg, x)
+
+    def fuses_output_layer(self) -> bool:
+        # by shape: wide reconstructions only (ops.recon_out_applies)
+        return ops.recon_out_applies(self.cfg.input_dim)
 
 
 # ---------------------------------------------------------------------------

@@ -24,7 +24,7 @@ Names used by the program:
 * campaign host stages, one span each per bucket
   (:mod:`repro.core.experiment`, :mod:`repro.core.campaign`):
   :data:`CAMPAIGN_STAGES`, plus ``campaign.plan`` around ``plan()``;
-* campaign counter: :data:`H2D_BYTES`;
+* campaign counters: :data:`H2D_BYTES`, :data:`FUSED_OUT_BUCKETS`;
 * service tick stages (:mod:`repro.serving.anomaly.service`):
   :data:`SERVE_STAGES`;
 * device stages, ``jax.named_scope`` inside the compiled cores (HLO
@@ -45,6 +45,9 @@ import jax.numpy as jnp
 CAMPAIGN_STAGES = ("campaign.build", "campaign.resolve", "campaign.dispatch",
                    "campaign.fetch", "campaign.post")
 H2D_BYTES = "campaign.h2d_bytes"
+#: 1 for each campaign bucket whose core runs the fused output-layer
+#: kernel (:func:`repro.kernels.ops.recon_out_layer`), 0 for the others
+FUSED_OUT_BUCKETS = "campaign.fused_out_buckets"
 SERVE_STAGES = ("serve.tick", "serve.group", "serve.pack", "serve.dispatch",
                 "serve.fetch", "serve.reassemble")
 SINGLE_SCOPES = ("alive_mask", "local_delta", "combine", "iso_fallback",
@@ -177,12 +180,15 @@ if _gc_hook not in gc.callbacks:
 def record_execution(tally: Tally, **fields) -> dict:
     """Append one execution's record, built from its closed ``tally``:
     ``wall_s``, the seconds of each of :data:`CAMPAIGN_STAGES` (as
-    ``build_s``, ``resolve_s``, ...), ``h2d_bytes``, ``gc_s`` (the
-    collection pause inside the execution) and ``fields``."""
+    ``build_s``, ``resolve_s``, ...), ``h2d_bytes``,
+    ``fused_out_buckets``, ``gc_s`` (the collection pause inside the
+    execution) and ``fields``."""
     gc_s = gc_pause_s(tally.start, tally.end)
     rec = {"start": tally.start, "end": tally.end,
            "wall_s": tally.end - tally.start,
-           "h2d_bytes": tally.counters[H2D_BYTES], "gc_s": gc_s}
+           "h2d_bytes": tally.counters[H2D_BYTES],
+           "fused_out_buckets": tally.counters[FUSED_OUT_BUCKETS],
+           "gc_s": gc_s}
     for name in CAMPAIGN_STAGES:
         rec[name.split(".", 1)[1] + "_s"] = tally.seconds[name]
     rec.update(fields)

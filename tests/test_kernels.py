@@ -11,6 +11,7 @@ import pytest
 from hypothesis_compat import given, settings, st
 
 from repro.kernels import ops, ref
+from repro.kernels import recon_out as ro
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.rglru_scan import rglru_scan
 from repro.kernels.rwkv6_scan import rwkv6_scan
@@ -304,6 +305,101 @@ def test_tolfl_combine_tree():
         want = (np.asarray(ns) / 10.0) @ flat
         np.testing.assert_allclose(np.asarray(got[key]).ravel(), want.ravel(),
                                    rtol=2e-5, atol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# fused output layer + reconstruction loss
+# ---------------------------------------------------------------------------
+# Tolerances, as a share of the largest magnitude of each result, by the
+# precision the kernel reads from jax_default_matmul_precision:
+# * float32: interpret mode runs float32 dots, so only the order of the
+#   sums differs from XLA's (measured 1.5e-7): 1e-5;
+# * tensorfloat32: three bfloat16 passes drop the lo*lo term, 2^-16 of
+#   each product, and round each lo to 8 bits (measured 6.7e-6): 5e-5;
+# * bfloat16: one pass rounds both operands to 8 bits, 2^-8 of each
+#   product (measured 3.4e-3): 2e-2.
+RECON_TOL = {"float32": 1e-5, "tensorfloat32": 5e-5, "bfloat16": 2e-2}
+# (rows, width, block_rows, precision): 37 rows in tiles of 16 leave a
+# ragged last tile of 5; 40 in tiles of 8 split evenly; None is one tile
+RECON_CASES = [(37, 256, 16, "float32"), (40, 256, 8, "float32"),
+               (37, 256, None, "float32"), (37, 256, 16, "tensorfloat32"),
+               (37, 128, 16, "bfloat16")]
+
+
+def _recon_inputs(rows, width, S=2, N=3, hid=128):
+    ks = jax.random.split(jax.random.PRNGKey(21), 4)
+    h = jax.nn.relu(rand(ks[0], (S, N, rows, hid)))
+    w = rand(ks[1], (S, N, hid, width), scale=0.1)
+    b = rand(ks[2], (S, N, width), scale=0.1)
+    x = rand(ks[3], (N, rows, width))            # shared by the scenarios
+    valid = (jnp.arange(rows)[None, :]
+             < jnp.array([rows, rows - 9, 0])[:N, None]).astype(jnp.float32)
+    return h, w, b, x, valid
+
+
+def _per_device(fn):
+    """vmap over (scenario, device), x and valid unbatched over scenarios
+    as in a campaign bucket."""
+    return jax.vmap(jax.vmap(fn), in_axes=(0, 0, 0, None, None))
+
+
+def _want(h, w, b, x, valid):
+    with jax.default_matmul_precision("float32"):
+        loss, (dh, dw, db) = jax.value_and_grad(
+            ref.recon_out_reference, argnums=(0, 1, 2))(h, w, b, x, valid)
+    return loss, dw, db, dh
+
+
+@pytest.mark.parametrize("rows,width,block_rows,precision", RECON_CASES)
+def test_recon_out_matches_grad_of_reference(rows, width, block_rows,
+                                             precision):
+    args = _recon_inputs(rows, width)
+
+    def fused(h, w, b, x, valid):
+        return ro.recon_out(h, w, b, x, valid / jnp.maximum(valid.sum(), 1.0),
+                            grads=True, n_passes=ro.passes(),
+                            block_rows=block_rows, interpret=True)
+    with jax.default_matmul_precision(precision):
+        got = _per_device(fused)(*args)
+    want = _per_device(_want)(*args)
+    for name, g, e in zip(("loss", "dw", "db", "dh"), got, want):
+        assert g.shape == e.shape, name
+        tol = RECON_TOL[precision] * float(jnp.max(jnp.abs(e)))
+        np.testing.assert_allclose(np.asarray(g), np.asarray(e), rtol=0,
+                                   atol=tol, err_msg=name)
+    # the device with no valid row: loss and gradients exactly zero
+    for g in got:
+        assert not np.any(np.asarray(g[:, 2]))
+
+
+def test_recon_out_loss_only():
+    h, w, b, x, valid = _recon_inputs(37, 256)
+
+    def fused(grads):
+        def f(h, w, b, x, valid):
+            return ro.recon_out(h, w, b, x,
+                                valid / jnp.maximum(valid.sum(), 1.0),
+                                grads=grads, n_passes=6, block_rows=16,
+                                interpret=True)
+        return _per_device(f)(h, w, b, x, valid)
+    only = fused(False)
+    assert len(only) == 1
+    np.testing.assert_array_equal(np.asarray(only[0]),
+                                  np.asarray(fused(True)[0]))
+
+
+def test_recon_out_layer_differentiates_params_not_data():
+    h, w, b, x, valid = _recon_inputs(37, 256)
+    h, w, b, x, valid = h[0, 1], w[0, 1], b[0, 1], x[1], valid[1]
+    g = jax.grad(ops.recon_out_layer, argnums=(0, 1, 2))(h, w, b, x, valid)
+    e = jax.grad(ref.recon_out_reference, argnums=(0, 1, 2))(
+        h, w, b, x, valid)
+    for gi, ei in zip(g, e):
+        np.testing.assert_array_equal(np.asarray(gi), np.asarray(ei))
+    assert float(ops.recon_out_layer(h, w, b, x, valid)) == float(
+        ref.recon_out_reference(h, w, b, x, valid))
+    with pytest.raises(NotImplementedError):
+        jax.grad(ops.recon_out_layer, argnums=3)(h, w, b, x, valid)
 
 
 # ---------------------------------------------------------------------------

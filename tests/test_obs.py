@@ -12,6 +12,10 @@ What is proven:
   single-model, multi-model and serving cores;
 * in a CPU profiler trace the ``campaign.*`` spans lie inside the
   enclosing ``execute`` annotation on one timeline (the shared clock);
+* the fused output-layer kernel by shape: a 112-wide core lowered for
+  the TPU has no kernel call, a 1024-wide one exactly one per local
+  step (and none lowered for the CPU); an execution record counts the
+  buckets that run it (``fused_out_buckets``);
 * the GC hook logs a forced full collection and ignores generation 0;
 * the executable cache's directory tag follows the cores' source
   digest.
@@ -32,7 +36,7 @@ from repro import obs
 from repro.api import (NO_FAILURE, AutoencoderConfig, CellSpec, DataSpec,
                        ExecPlan, ExperimentSpec, FailureSpec, SeedSpec,
                        SimConfig, TraceSpec, execute, plan)
-from repro.core import compilecache
+from repro.core import compilecache, experiment
 from repro.core.baselines import (MultiModelConfig, _build_multimodel_core,
                                   as_multimodel_trace,
                                   prepare_multimodel_arrays)
@@ -185,6 +189,66 @@ def test_score_core_has_its_scope(small):
     x = jnp.asarray(tx[:32].reshape(2, 16, -1))
     found = _scopes_in(score, rows, jnp.int32(1), x)
     assert set(obs.SERVE_SCOPES) <= found
+
+
+# ---------------------------------------------------------------------------
+# the fused output layer, by shape
+# ---------------------------------------------------------------------------
+WIDE = AutoencoderConfig(input_dim=1024, hidden=(16,), code_dim=4,
+                         dropout=0.2)
+
+
+@pytest.fixture(scope="module")
+def wide(small):
+    """``small``'s federation at the narrowest width that takes the
+    kernel (its rows tiled to 1024 features)."""
+    _, dx, counts, tx, ty = small
+    reps = -(-WIDE.input_dim // dx.shape[-1])
+    return (WIDE, np.tile(dx, reps)[..., :WIDE.input_dim], counts,
+            np.tile(tx, reps)[:, :WIDE.input_dim], ty)
+
+
+def _kernel_calls(core, data, track_iso, platform):
+    ae, dx, counts, tx, _ = data
+    cfg = SimConfig(scheme="tolfl", num_devices=6, num_clusters=3,
+                    rounds=ROUNDS, lr=1e-3, local_epochs=2)
+    topo = cfg.topology()
+    d, c, v = _prepare_arrays(cfg, dx, counts)
+    traced = jax.jit(core(ae, cfg, 6, 3, track_iso=track_iso,
+                          score_history=False)).trace(
+        d, c, v, jnp.asarray(tx), jnp.asarray(topo.device_cluster_array()),
+        jnp.asarray(np.array(topo.heads)), jnp.ones((3,), jnp.float32),
+        as_trace(FailureSpec(1, "server"), topo), 0)
+    return traced.lower(lowering_platforms=(platform,)).as_text().count(
+        "tpu_custom_call")
+
+
+@pytest.mark.parametrize("track_iso", [False, True])
+def test_fused_output_layer_by_width(small, wide, track_iso):
+    # the local steps are a scan: one call in its body is one per step
+    assert _kernel_calls(_build_core_arrays, wide, track_iso, "tpu") == 1
+    assert _kernel_calls(_build_core_arrays, wide, track_iso, "cpu") == 0
+    assert _kernel_calls(_build_core_arrays, small, track_iso, "tpu") == 0
+    assert as_detector(wide[0]).fuses_output_layer()
+    assert not as_detector(small[0]).fuses_output_layer()
+
+
+@pytest.mark.parametrize("backend,want", [("tpu", (2, 0)), ("cpu", (0, 0))])
+def test_execution_record_counts_fused_buckets(small, wide, monkeypatch,
+                                               backend, want):
+    monkeypatch.setattr(experiment, "_backend", lambda: backend)
+    got = []
+    for data in (wide, small):
+        ae, dx, counts, tx, ty = data
+        execute(plan(ExperimentSpec(
+            data=DataSpec(model=ae, device_x=dx, device_counts=counts,
+                          test_x=tx, test_y=ty),
+            base=SimConfig(num_devices=6, rounds=ROUNDS, lr=1e-3),
+            cells=(CellSpec("tolfl", 3), CellSpec("fl", 1)),
+            traces=TraceSpec(traces=(NO_FAILURE,)),
+            seeds=SeedSpec.range(1))))
+        got.append(obs.executions()[-1]["fused_out_buckets"])
+    assert tuple(got) == want
 
 
 def test_campaign_spans_share_the_profiler_clock(small, tmp_path):

@@ -14,7 +14,11 @@ autoencoder, Comms-ML, N=10, 60 rounds, E=3):
   per-cell tolfl bucket;
 * the serving bucket core at batch buckets 1, 8 and 64;
 * the Pallas kernels ``tolfl_combine`` and ``rglru_scan`` in compiled
-  (not interpret) mode.
+  (not interpret) mode;
+* at the CIFAR-10 width (3072 features, 1313 rows a device, so the last
+  row tile is partial): the fl iso-tracking bucket, whose loss takes the
+  fused output-layer kernel ``recon_out``, and that kernel alone under
+  the bucket's two vmaps.
 
 The topology is described inside a module fixture, never at import:
 only one process may load the TPU library, and the suite runs under
@@ -23,15 +27,18 @@ compilation cache is off around the compiles (an entry written for a
 described chip cannot be read back without one).
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
 from benchmarks.datasets import base_config, data_spec, prepare
-from repro.api import (NO_FAILURE, CellSpec, ExperimentSpec, FailureSpec,
-                       SeedSpec, TraceSpec, plan)
+from repro.api import (NO_FAILURE, CellSpec, DataSpec, ExperimentSpec,
+                       FailureSpec, SeedSpec, SimConfig, TraceSpec, plan)
+from repro.configs.autoencoder_paper import CIFAR10
 from repro.core import campaign, experiment
 from repro.models.detector import as_detector
 from repro.serving.anomaly import engine
@@ -142,4 +149,60 @@ def test_rglru_scan_compiles_for_v5e(one_chip):
     a = jax.ShapeDtypeStruct((8, 256, 512), jnp.float32, sharding=one_chip)
     h0 = jax.ShapeDtypeStruct((8, 512), jnp.float32, sharding=one_chip)
     compiled = jax.jit(rglru_scan).lower(a, a, h0).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+# the cifar10-ae benchmark configuration's device shape
+CIFAR_ROWS, CIFAR_DEVICES = 1313, 10
+
+
+@pytest.fixture
+def tensorfloat32():
+    """The benchmark's matmul precision: three bfloat16 passes."""
+    with jax.default_matmul_precision("tensorfloat32"):
+        yield
+
+
+def test_cifar_fl_bucket_compiles_with_fused_output_layer(one_chip,
+                                                          tensorfloat32):
+    width = CIFAR10.input_dim
+    data = DataSpec(
+        model=CIFAR10,
+        device_x=np.zeros((CIFAR_DEVICES, CIFAR_ROWS, width), np.float32),
+        device_counts=np.full((CIFAR_DEVICES,), CIFAR_ROWS),
+        test_x=np.zeros((512, width), np.float32),
+        test_y=np.arange(512) % 2)
+    p = plan(ExperimentSpec(
+        data=data, cells=(CellSpec("fl", 1),),
+        base=SimConfig(num_devices=CIFAR_DEVICES, rounds=ROUNDS, lr=1e-3,
+                       local_epochs=5),
+        traces=TraceSpec.explicit(NO_FAILURE,
+                                  FailureSpec(ROUNDS // 2, "server")),
+        seeds=SeedSpec((0, 1))))
+    (b,) = p.buckets
+    assert (b.kind, b.track_iso) == ("single", True)
+    key = campaign._exe_key(*experiment._bucket_exe_args(p.spec.data, b))
+    avals = experiment._bucket_avals(p.spec.data, b,
+                                     [p.cells[i] for i in b.cell_indices])
+    text = campaign._build_executable(*key).lower(
+        *_on(one_chip, avals)).compile(
+            compiler_options=campaign._AOT_COMPILER_OPTIONS).as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    # no (scenario, device, row, feature) float32 array anywhere: x is
+    # not broadcast to the scenarios, x_hat and its error stay in VMEM
+    assert not re.search(rf"f32\[\d+,{CIFAR_DEVICES},{CIFAR_ROWS},{width}\]",
+                         text)
+
+
+def test_recon_out_compiles_for_v5e(one_chip, tensorfloat32):
+    from repro.kernels.recon_out import passes, recon_out
+    S, N, R, H, F = 4, CIFAR_DEVICES, CIFAR_ROWS, 128, CIFAR10.input_dim
+
+    def step(h, w, b, x, valid):
+        return recon_out(h, w, b, x, valid, grads=True, n_passes=passes())
+    shapes = [(S, N, R, H), (S, N, H, F), (S, N, F), (N, R, F), (N, R)]
+    compiled = jax.jit(jax.vmap(jax.vmap(step),
+                                in_axes=(0, 0, 0, None, None))).lower(
+        *(jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+          for s in shapes)).compile()
     assert "tpu_custom_call" in compiled.as_text()
