@@ -41,7 +41,7 @@ from typing import Dict, List
 import jax.numpy as jnp
 import numpy as np
 
-from cbench import data, failures, flops, reference
+from cbench import data, failures, harness, reference
 
 MULTI = ("fedgroup", "ifca", "fesem")
 SPAN_NAMES = ("plan", "execute")
@@ -54,22 +54,19 @@ def _rng(seed: int, stream: int) -> np.random.Generator:
 class Campaign:
     def __init__(self, config: dict, traffic: dict, seed: int, chips: int,
                  spans, seconds: float):
-        from repro.api import (AutoencoderConfig, DataSpec, ExecPlan,
-                               SimConfig)
+        from repro.api import DataSpec, ExecPlan, SimConfig
         self.config, self.traffic, self.seed = config, traffic, seed
         self.spans = spans
         m, topo = config["model"], config["topology"]
         self.model = m
+        self.body = harness.body(m["kind"])
         self.n_dev = int(topo["num_devices"])
         self.rounds = int(topo["rounds"])
         self.local_steps = int(topo["local_epochs"])
         self.lr = float(topo["lr"])
         self.fed = data.federation(config["data"], topo, seed)
         self.data_spec = DataSpec(
-            model=AutoencoderConfig(
-                input_dim=int(m["input_dim"]), hidden=tuple(m["hidden"]),
-                code_dim=int(m["code_dim"]), dropout=float(m["dropout"]),
-                act=m["act"]),
+            model=self.body.program_model(m),
             device_x=self.fed.device_x, device_counts=self.fed.counts,
             test_x=self.fed.test_x, test_y=self.fed.test_y)
         self.base = SimConfig(
@@ -78,10 +75,12 @@ class Campaign:
             lr=self.lr, local_epochs=self.local_steps, dropout=True)
         self.exec_plan = ExecPlan(aot=True, shard=chips > 1)
         self.rng = _rng(seed, 1)
-        self.flops_per_scenario = flops.train_flops_per_scenario(
+        self.flops_per_scenario = self.body.train_flops_per_scenario(
             m, self.fed.counts, self.local_steps, self.rounds)
         self.records: List[dict] = []
         self.exe_load_s = 0.0
+        #: op-name prefixes of the body's kernels, for the trace reduction
+        self.kernel_prefixes: tuple = ()
 
     # ------------------------------------------------------------------
     def _cell_traces(self, scheme: str, k: int) -> List[dict]:
@@ -130,10 +129,35 @@ class Campaign:
             p = plan(spec)
         with self.spans("execute"):
             res = execute(p)
+        t = time.perf_counter() - t0
         return {"results": res.results, "traces": traces,
                 "report": res.compile_report,
-                "scenarios": res.num_scenarios,
-                "seconds": time.perf_counter() - t0}
+                "scenarios": res.num_scenarios, "seconds": t,
+                "kernels": self._kernel_work(p)}
+
+    def _kernel_work(self, p) -> Dict[str, dict]:
+        """Calls, FLOPs and HBM bytes of each of the body's kernels over
+        one execution, from its plan's buckets: a bucket makes one call
+        per local step (rounds x E) per chunk of scenarios and per chip
+        it is sharded over, each call batching that chip's scenarios of
+        the chunk."""
+        counts = getattr(self.body, "kernel_counts", None)
+        out: Dict[str, dict] = {}
+        if counts is None:
+            return out
+        rows = int(self.fed.device_x.shape[1])
+        for b in p.buckets:
+            chips = b.devices or 1
+            geometry = {"kind": b.kind, "scenarios": b.chunk // chips,
+                        "devices": self.n_dev, "rows": rows}
+            calls = b.num_chunks * chips * self.rounds * self.local_steps
+            for prefix, c in counts(self.model, geometry).items():
+                w = out.setdefault(prefix,
+                                   {"calls": 0, "flops": 0, "bytes": 0})
+                w["calls"] += calls
+                w["flops"] += calls * c["flops"]
+                w["bytes"] += calls * c["bytes"]
+        return out
 
     def warm_up(self) -> None:
         """One execution: compiles or loads every bucket and warms every
@@ -142,6 +166,7 @@ class Campaign:
         self.exe_load_s = sum(b.compile_s for b in rec["report"].buckets
                               if b.cache in ("disk", "compiled"))
         self.warm_report = rec["report"]
+        self.kernel_prefixes = tuple(sorted(rec["kernels"]))
 
     def window(self, seconds: float) -> dict:
         """Whole executions until ``seconds`` have passed, ``in_flight``
@@ -179,6 +204,12 @@ class Campaign:
             raise errors[0]
         self.records.extend(records)
         scen = sum(r["scenarios"] for r in self.records)
+        kernels: Dict[str, dict] = {}
+        for r in self.records:
+            for prefix, w in r["kernels"].items():
+                k = kernels.setdefault(prefix, dict.fromkeys(w, 0))
+                for f, v in w.items():
+                    k[f] += v
         failed = 0
         for r in self.records:
             for res in r["results"]:
@@ -193,6 +224,7 @@ class Campaign:
                              "plan_s": self.spans.seconds["plan"],
                              "flops": self.flops_per_scenario * scen,
                              "exe_load_s": self.exe_load_s,
+                             "kernels": kernels,
                              "execution_s": [r["seconds"]
                                              for r in self.records]}}
 
@@ -232,7 +264,6 @@ class Campaign:
         trace = rec["traces"][ci][int(res.trace_index[b])]
         seed = int(res.seed[b])
         fed, m = self.fed, self.model
-        rate = float(m["dropout"])
         if c["scheme"] in MULTI:
             if c["scheme"] != "ifca":
                 raise NotImplementedError(c["scheme"])
@@ -244,15 +275,13 @@ class Campaign:
             a_mod = failures.alive_rounds(grp, k, rounds, (2,))
             return reference.ifca_scenario(
                 m, fed.device_x, fed.counts, fed.test_x, fed.test_y, a_dev,
-                a_mod, seed, num_models=k, lr=self.lr, rate=rate,
-                precision=precision)
+                a_mod, seed, num_models=k, lr=self.lr, precision=precision)
         k = {"fl": 1, "sbt": self.n_dev}.get(c["scheme"], int(c["k"]))
         alive = failures.alive_rounds(trace, self.n_dev, self.rounds)
         return reference.single_scenario(
             m, fed.device_x, fed.counts, fed.test_x, fed.test_y, alive, k,
             seed, rounds=self.rounds, local_steps=self.local_steps,
-            lr=self.lr, rate=rate, track_iso=c["scheme"] == "fl",
-            precision=precision)
+            lr=self.lr, track_iso=c["scheme"] == "fl", precision=precision)
 
     @staticmethod
     def program_one(res, b: int) -> Dict[str, np.ndarray]:

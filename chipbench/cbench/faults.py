@@ -57,14 +57,19 @@ def state_unchanged():
 
 def half_batch():
     """Each device's loss leaves out the second half of its rows and
-    takes the mean over the rest."""
-    from repro.models.detector import AutoencoderDetector
-    orig = AutoencoderDetector.loss
+    takes the mean over the rest: the ``loss`` of every body's program
+    class (``program_loss_class()`` of each file in ``bodies/``)."""
+    from cbench import harness
+    classes = {harness.body(k).program_loss_class()
+               for k in harness.body_kinds()}
 
-    def half(self, params, x, valid, key=None):
-        keep = jnp.arange(valid.shape[-1]) < valid.shape[-1] // 2
-        return orig(self, params, x, valid * keep, key)
-    return _isolated(mock.patch.object(AutoencoderDetector, "loss", half))
+    def halved(orig):
+        def half(self, params, x, valid, key=None):
+            keep = jnp.arange(valid.shape[-1]) < valid.shape[-1] // 2
+            return orig(self, params, x, valid * keep, key)
+        return half
+    return _isolated(*(mock.patch.object(cls, "loss", halved(cls.loss))
+                       for cls in classes))
 
 
 def masks_ignored():

@@ -1,13 +1,38 @@
 """One run of one cell: set-up, the measured window, the check.
 
-The cell's configuration, traffic mix and per-layer metric readers are
-files found by name (the configuration entry's ``file``,
-``traffic/<traffic>.json`` for the cell's ``traffic``,
+The cell's configuration, traffic mix, detector body and per-layer
+metric readers are files found by name (the configuration entry's
+``file``, ``traffic/<traffic>.json`` for the cell's ``traffic``,
+``bodies/<kind>.py`` for the configuration's ``model.kind``,
 ``metrics/<metric>.py``); the traffic file's ``kind`` names the general
 generator that reads it (:mod:`cbench.campaign`, :mod:`cbench.serve`).
+
+A body file holds everything the harness knows of one detector body:
+
+``program_model(model)``
+    what the program's ``DataSpec(model=...)`` is given;
+``program_loss_class()``
+    the program class whose ``loss`` the ``half_batch`` fault wraps;
+``program_params(params, model)``
+    reference-layout parameters as the program's pytree;
+``init(key, model)``, ``train_loss(params, x, valid, key, precision,
+model)``, ``scores(params, x, precision, model)``
+    the plain float32 reference of the body, which imports nothing of
+    the program and takes every product through
+    :func:`cbench.reference.matmul`;
+``bank(key, model, rows)`` (for a scoring-service cell)
+    ``rows`` scoring-service models drawn from ``key``, in the
+    reference's layout;
+``param_count(model)``, ``train_flops_per_scenario(model, counts,
+local_steps, rounds)``
+    the counts ``mfu.campaign`` reads;
+``kernel_counts(model, geometry)`` (optional)
+    FLOPs and HBM bytes of one call of each of the body's kernels, by
+    op-name prefix, for one local step of a bucket.
 """
 from __future__ import annotations
 
+import functools
 import importlib.util
 import json
 import os
@@ -15,7 +40,8 @@ import shutil
 import sys
 import tempfile
 import time
-from typing import Callable, Dict, Optional
+from types import ModuleType
+from typing import Callable, Dict, List, Optional
 
 GENERATORS = {"campaign": ("cbench.campaign", "Campaign"),
               "serve": ("cbench.serve", "Serve")}
@@ -27,6 +53,38 @@ class NoChip(RuntimeError):
 
 def bench_dir() -> str:
     return os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+#: where ``body(kind)`` looks for ``<kind>.py``
+BODY_DIR = os.path.join(bench_dir(), "bodies")
+
+
+def body(kind: str) -> ModuleType:
+    """The body file of a model kind, loaded once per path."""
+    path = os.path.join(BODY_DIR, f"{kind}.py")
+    if not os.path.isfile(path):
+        raise FileNotFoundError(
+            f"no body file for model kind {kind!r}: looked for {path}")
+    return _load_body(path, kind)
+
+
+@functools.lru_cache(maxsize=None)
+def _load_body(path: str, kind: str) -> ModuleType:
+    return _load(path, "chipbench_body_" + kind)
+
+
+def body_kinds() -> List[str]:
+    """The kinds that have a body file."""
+    return sorted(os.path.splitext(f)[0] for f in os.listdir(BODY_DIR)
+                  if f.endswith(".py"))
+
+
+def _load(path: str, name: str) -> ModuleType:
+    spec = importlib.util.spec_from_file_location(
+        name.replace(".", "_").replace("-", "_"), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def load_json(path: str) -> dict:
@@ -52,13 +110,8 @@ def cell_files(bench: dict, workload: str, checkout: str):
 
 
 def metric_reader(name: str) -> Callable[[dict], Optional[float]]:
-    path = os.path.join(bench_dir(), "metrics", f"{name}.py")
-    spec = importlib.util.spec_from_file_location(
-        "chipbench_metric_" + name.replace(".", "_").replace("-", "_"),
-        path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _load(os.path.join(bench_dir(), "metrics", f"{name}.py"),
+                 "chipbench_metric_" + name).read
 
 
 def applies(metric: dict, workload: str) -> bool:
@@ -140,7 +193,8 @@ def run_cell(bench: dict, workload: str, seed: int, seconds: float,
         try:
             rec = tracereduce.load_xplane(
                 trace_dir, getattr(mod, "SPAN_NAMES"))
-            reduced = tracereduce.reduce_trace(rec)
+            reduced = tracereduce.reduce_trace(
+                rec, kernels=getattr(gen, "kernel_prefixes", ()))
         except tracereduce.NoDevicePlane:
             if require_tpu:
                 raise
@@ -166,7 +220,8 @@ def run_cell(bench: dict, workload: str, seed: int, seconds: float,
     else:
         ctx = {"kind": traffic["kind"], "counters": win["counters"],
                "trace": reduced, "window_s": win["seconds"],
-               "chips": chips, "peaks": device_peaks}
+               "chips": chips, "peaks": device_peaks,
+               "precision": config["matmul_precision"]}
         for m in bench["per_layer"]:
             if not applies(m, workload):
                 continue

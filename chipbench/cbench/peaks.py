@@ -22,3 +22,10 @@ def peaks(device_kind: str) -> Dict[str, float]:
         raise KeyError(f"no peaks for device kind {device_kind!r}; add "
                        f"them to {__name__}.PEAKS with their source") \
             from None
+
+
+#: bfloat16 MXU passes per float32 product, by the configuration's
+#: ``matmul_precision`` (JAX's names): a float32 product costs this many
+#: times its FLOPs at the bf16 peak
+MATMUL_PASSES: Dict[str, int] = {"bfloat16": 1, "tensorfloat32": 3,
+                                 "float32": 6}

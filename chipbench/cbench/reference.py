@@ -1,13 +1,12 @@
 """Plain float32 references of the campaign and the scoring service.
 
 Independent of the program: nothing here imports ``repro``.  The
-semantics follow the paper (Algorithm 1, Section V) as the program
-states them:
+detector body (its initial weights, training loss and anomaly score)
+is the configuration's body file, ``chipbench/bodies/<kind>.py``
+(:func:`cbench.harness.body`); this module holds what every body
+shares.  The semantics follow the paper (Algorithm 1, Section V) as
+the program states them:
 
-* the autoencoder: fully connected, ReLU hidden layers, linear output,
-  inverted dropout at rate 0.2 on every hidden layer while training;
-  initial weights N(0, 1/fan_in), zero biases; the anomaly score of a
-  row is its squared reconstruction error;
 * a device's update: E local SGD steps on its masked mean loss, sent as
   (theta - theta_E) / lr (the plain gradient when E = 1);
 * Tol-FL's hierarchical combine written as what it equals (the paper's
@@ -21,7 +20,8 @@ states them:
   aggregator (server event) freezes model 0;
 * the random streams: ``PRNGKey(seed)``; per round ``rkey, dkey =
   split(rkey)`` and one key per device from ``split(dkey, N)``; local
-  step e folds e into the device key; dropout layer i folds in i.
+  step e folds e into the device key, and the body draws its dropout
+  from that key.
 
 Every matrix product goes through :func:`matmul`: ``"highest"`` is
 float32 (six bfloat16 passes on a TPU); ``"bf16_3x"`` (three passes,
@@ -33,13 +33,14 @@ nearest one below it: the control that the comparison must reject.
 from __future__ import annotations
 
 import functools
-import math
-from typing import Dict, List, Sequence
+import json
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from cbench import harness
 from cbench.auroc import auroc_rows
 
 PRECISIONS = ("highest", "bf16_3x", "bf16")
@@ -114,50 +115,22 @@ def matmul(a, b, precision: str):
 
 
 # ---------------------------------------------------------------------------
-# The autoencoder
+# Detector bodies
 # ---------------------------------------------------------------------------
-def layer_dims(model: dict) -> List[int]:
-    hidden = list(model["hidden"])
-    return ([model["input_dim"]] + hidden + [model["code_dim"]]
-            + hidden[::-1] + [model["input_dim"]])
+def body_key(model: dict) -> Tuple[str, str]:
+    """A configuration's model as a static, hashable argument: its kind
+    and the whole model dict, frozen as canonical JSON."""
+    return model["kind"], json.dumps(model, sort_keys=True)
 
 
-def init_params(key, dims: Sequence[int]) -> List[Dict[str, jax.Array]]:
-    ks = jax.random.split(key, len(dims) - 1)
-    return [{"w": (jax.random.normal(ks[i], (dims[i], dims[i + 1]))
-                   * (1.0 / math.sqrt(dims[i]))).astype(jnp.float32),
-             "b": jnp.zeros((dims[i + 1],), jnp.float32)}
-            for i in range(len(dims) - 1)]
-
-
-def forward(params, x, precision: str, dropout_key=None,
-            rate: float = 0.0):
-    h = x
-    for i, layer in enumerate(params):
-        h = matmul(h, layer["w"], precision) + layer["b"]
-        if i < len(params) - 1:
-            h = jnp.maximum(h, 0.0)
-            if dropout_key is not None and rate > 0:
-                keep = jax.random.bernoulli(
-                    jax.random.fold_in(dropout_key, i), 1.0 - rate,
-                    h.shape)
-                h = jnp.where(keep, h / (1.0 - rate), 0.0)
-    return h
-
-
-def scores(params, x, precision: str):
-    """Squared reconstruction error per row."""
-    return jnp.sum(jnp.square(x - forward(params, x, precision)), axis=-1)
-
-
-def train_loss(params, x, valid, key, precision: str, rate: float):
-    err = jnp.sum(jnp.square(x - forward(params, x, precision, key, rate)),
-                  axis=-1)
-    return jnp.sum(err * valid) / jnp.maximum(jnp.sum(valid), 1.0)
+def body_of(key: Tuple[str, str]):
+    """(body module, model dict) of a :func:`body_key`."""
+    kind, frozen = key
+    return harness.body(kind), json.loads(frozen)
 
 
 def _tree_axpy(a, x, y):
-    """y + a * x over two parameter lists."""
+    """y + a * x over two parameter trees."""
     return jax.tree.map(lambda xi, yi: yi + a * xi, x, y)
 
 
@@ -165,26 +138,29 @@ def _tree_axpy(a, x, y):
 # Single-model schemes (FL / SBT / Tol-FL)
 # ---------------------------------------------------------------------------
 @functools.partial(jax.jit, static_argnames=(
-    "dims", "rounds", "local_steps", "lr", "rate", "track_iso",
-    "precision"))
+    "body", "rounds", "local_steps", "lr", "track_iso", "precision"))
 def _single(dx, counts, valid, tx, alive, head_of, is_head, seed, *,
-            dims, rounds, local_steps, lr, rate, track_iso, precision):
+            body, rounds, local_steps, lr, track_iso, precision):
     n_dev = dx.shape[0]
+    mod, model = body_of(body)
     key = jax.random.PRNGKey(seed)
-    params = init_params(key, dims)
-    grad = jax.grad(train_loss)
+    params = mod.init(key, model)
+    grad = jax.grad(mod.train_loss)
+
+    def scores(p, x):
+        return mod.scores(p, x, precision, model)
 
     def local_update(p, x, v, k):
         if local_steps == 1:
-            return grad(p, x, v, k, precision, rate)
+            return grad(p, x, v, k, precision, model)
         q = p
         for e in range(local_steps):
-            g = grad(q, x, v, jax.random.fold_in(k, e), precision, rate)
+            g = grad(q, x, v, jax.random.fold_in(k, e), precision, model)
             q = jax.tree.map(lambda a, b: a - lr * b, q, g)
         return jax.tree.map(lambda a, b: (a - b) / lr, p, q)
 
     def mean_score(p):
-        return jnp.mean(scores(p, tx, precision))
+        return jnp.mean(scores(p, tx))
 
     def round_fn(carry, alive_r):
         params, iso, rkey = carry
@@ -225,16 +201,16 @@ def _single(dx, counts, valid, tx, alive, head_of, is_head, seed, *,
                         params)
     (params, iso, _), (loss, iso_loss, dead) = jax.lax.scan(
         round_fn, (params, iso0, key), alive)
-    final = scores(params, tx, precision)
-    iso_final = (jax.vmap(lambda p: scores(p, tx, precision))(iso)
+    final = scores(params, tx)
+    iso_final = (jax.vmap(lambda p: scores(p, tx))(iso)
                  if track_iso else jnp.zeros((n_dev, 0), jnp.float32))
     return loss, iso_loss, dead, final, iso_final
 
 
 def single_scenario(model: dict, dx, counts, tx, test_y, alive: np.ndarray,
                     num_clusters: int, seed: int, *, rounds: int,
-                    local_steps: int, lr: float, rate: float,
-                    track_iso: bool, precision: str) -> dict:
+                    local_steps: int, lr: float, track_iso: bool,
+                    precision: str) -> dict:
     """One FL / SBT / Tol-FL scenario: the reported test-loss curve and
     AUROC.  ``alive`` is the (rounds, N) liveness of the trace."""
     n_dev = dx.shape[0]
@@ -247,8 +223,8 @@ def single_scenario(model: dict, dx, counts, tx, test_y, alive: np.ndarray,
         jnp.asarray(dx), jnp.asarray(counts, jnp.float32),
         jnp.asarray(valid), jnp.asarray(tx), jnp.asarray(alive),
         jnp.asarray(head_of, jnp.int32), jnp.asarray(is_head),
-        jnp.int32(seed), dims=tuple(layer_dims(model)), rounds=rounds,
-        local_steps=local_steps, lr=lr, rate=rate, track_iso=track_iso,
+        jnp.int32(seed), body=body_key(model), rounds=rounds,
+        local_steps=local_steps, lr=lr, track_iso=track_iso,
         precision=precision)
     loss, iso_loss = np.asarray(loss), np.asarray(iso_loss)
     dead = np.asarray(dead) > 0
@@ -266,19 +242,23 @@ def single_scenario(model: dict, dx, counts, tx, test_y, alive: np.ndarray,
 # IFCA
 # ---------------------------------------------------------------------------
 @functools.partial(jax.jit, static_argnames=(
-    "dims", "num_models", "lr", "rate", "precision"))
-def _ifca(dx, counts, valid, tx, alive_dev, alive_model, seed, *, dims,
-          num_models, lr, rate, precision):
+    "body", "num_models", "lr", "precision"))
+def _ifca(dx, counts, valid, tx, alive_dev, alive_model, seed, *, body,
+          num_models, lr, precision):
     n_dev = dx.shape[0]
+    mod, model = body_of(body)
     key = jax.random.PRNGKey(seed)
     k_init, _, k_train = jax.random.split(key, 3)
-    models = [init_params(jax.random.fold_in(k_init, j), dims)
+    models = [mod.init(jax.random.fold_in(k_init, j), model)
               for j in range(num_models)]
     models = jax.tree.map(lambda *xs: jnp.stack(xs), *models)
     assign0 = jnp.arange(n_dev) % num_models
 
+    def scores(p, x):
+        return mod.scores(p, x, precision, model)
+
     def loss_of(p, x, v, k):
-        return train_loss(p, x, v, k, precision, rate)
+        return mod.train_loss(p, x, v, k, precision, model)
 
     def round_fn(carry, xs):
         models, _, rkey = carry
@@ -303,18 +283,18 @@ def _ifca(dx, counts, valid, tx, alive_dev, alive_model, seed, *, dims,
         models = jax.tree.map(
             lambda p, g: p - lr * gate.reshape((-1,) + (1,) * (g.ndim - 1))
             * g, models, mean_g)
-        s = jax.vmap(lambda p: scores(p, tx, precision))(models)
+        s = jax.vmap(lambda p: scores(p, tx))(models)
         return (models, assign, rkey), jnp.mean(jnp.min(s, axis=0))
 
     (models, _, _), loss = jax.lax.scan(
         round_fn, (models, assign0, k_train), (alive_dev, alive_model))
-    final = jax.vmap(lambda p: scores(p, tx, precision))(models)
+    final = jax.vmap(lambda p: scores(p, tx))(models)
     return loss, final
 
 
 def ifca_scenario(model: dict, dx, counts, tx, test_y, alive_dev: np.ndarray,
                   alive_model: np.ndarray, seed: int, *, num_models: int,
-                  lr: float, rate: float, precision: str) -> dict:
+                  lr: float, precision: str) -> dict:
     """One IFCA scenario: the per-sample-min loss curve, the best single
     model's AUROC and the AUROC of the per-sample minimum score."""
     valid = (np.arange(dx.shape[1])[None, :]
@@ -323,8 +303,8 @@ def ifca_scenario(model: dict, dx, counts, tx, test_y, alive_dev: np.ndarray,
         jnp.asarray(dx), jnp.asarray(counts, jnp.float32),
         jnp.asarray(valid), jnp.asarray(tx), jnp.asarray(alive_dev),
         jnp.asarray(alive_model), jnp.int32(seed),
-        dims=tuple(layer_dims(model)), num_models=num_models, lr=lr,
-        rate=rate, precision=precision)
+        body=body_key(model), num_models=num_models, lr=lr,
+        precision=precision)
     final = np.asarray(final)
     best = float(np.max(auroc_rows(final, test_y)))
     multi = float(auroc_rows(final.min(axis=0)[None], test_y)[0])
@@ -334,20 +314,25 @@ def ifca_scenario(model: dict, dx, counts, tx, test_y, alive_dev: np.ndarray,
 # ---------------------------------------------------------------------------
 # Scoring service
 # ---------------------------------------------------------------------------
-@functools.partial(jax.jit, static_argnames=("precision",))
-def _score_rows(row_params, rows, x, *, precision):
+@functools.partial(jax.jit, static_argnames=("body", "precision"))
+def _score_rows(row_params, rows, x, *, body, precision):
     """Scores of windows ``x`` (B, W, D) each against bank row
     ``rows[b]``."""
+    mod, model = body_of(body)
+
     def one(r, xw):
         p = jax.tree.map(lambda t: t[r], row_params)
-        return scores(p, xw, precision)
+        return mod.scores(p, xw, precision, model)
     return jax.vmap(one)(rows, x)
 
 
-def service_scores(row_params, rows: np.ndarray, windows: np.ndarray,
-                   precision: str, block: int = 4096) -> np.ndarray:
-    """(B, W) reference scores of every window against its bank row, in
-    blocks of ``block`` windows (the last block padded)."""
+def service_scores(model: dict, row_params, rows: np.ndarray,
+                   windows: np.ndarray, precision: str,
+                   block: int = 4096) -> np.ndarray:
+    """(B, W) reference scores of every window against its bank row
+    (``row_params`` in the body's reference layout, each leaf with a
+    leading row axis), in blocks of ``block`` windows (the last block
+    padded)."""
     out = []
     for s in range(0, len(rows), block):
         r = rows[s:s + block]
@@ -357,6 +342,7 @@ def service_scores(row_params, rows: np.ndarray, windows: np.ndarray,
             r = np.concatenate([r, np.zeros(pad, r.dtype)])
             x = np.concatenate([x, np.zeros((pad,) + x.shape[1:], x.dtype)])
         got = _score_rows(row_params, jnp.asarray(r, jnp.int32),
-                          jnp.asarray(x), precision=precision)
+                          jnp.asarray(x), body=body_key(model),
+                          precision=precision)
         out.append(np.asarray(got)[:block - pad])
     return np.concatenate(out, 0)

@@ -34,7 +34,6 @@ Traffic file keys (``kind: "serve"``):
 from __future__ import annotations
 
 import functools
-import math
 import time
 from typing import Dict
 
@@ -42,7 +41,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from cbench import data, failures, reference
+from cbench import data, failures, harness, reference
 
 SPAN_NAMES = ("sleep", "submit", "tick")
 
@@ -51,18 +50,12 @@ def _rng(seed: int, stream: int) -> np.random.Generator:
     return np.random.default_rng([seed, stream])
 
 
-@functools.partial(jax.jit, static_argnames=("dims", "rows"))
-def make_bank(key, *, dims, rows):
-    """Bank weights for ``rows`` models, made on the device in one call:
-    N(0, 1/fan_in) weights and N(0, 0.1^2) biases."""
-    params = {}
-    for i in range(len(dims) - 1):
-        kw, kb = jax.random.split(jax.random.fold_in(key, i))
-        params[f"fc{i}"] = {
-            "w": jax.random.normal(kw, (rows, dims[i], dims[i + 1]))
-            * (1.0 / math.sqrt(dims[i])),
-            "b": 0.1 * jax.random.normal(kb, (rows, dims[i + 1]))}
-    return params
+@functools.partial(jax.jit, static_argnames=("body", "rows"))
+def make_bank(key, *, body, rows):
+    """Bank weights for ``rows`` models in the body's reference layout,
+    made on the device in one call (the body's ``bank``)."""
+    mod, model = reference.body_of(body)
+    return mod.bank(key, model, rows)
 
 
 def zipf_counts(n: int, clients: int, s: float) -> np.ndarray:
@@ -78,10 +71,8 @@ def zipf_counts(n: int, clients: int, s: float) -> np.ndarray:
 class Serve:
     def __init__(self, config: dict, traffic: dict, seed: int, chips: int,
                  spans, seconds: float):
-        from repro.api import (AnomalyService, AutoencoderConfig,
-                               FailureTrace, ModelBank, ServiceConfig,
-                               Topology)
-        from repro.models.detector import AutoencoderDetector
+        from repro.api import (AnomalyService, FailureTrace, ModelBank,
+                               ServiceConfig, Topology, as_detector)
         self.config, self.traffic, self.seed = config, traffic, seed
         self.spans = spans
         m, topo = config["model"], config["topology"]
@@ -121,16 +112,14 @@ class Serve:
         horizon = self.offset + self.ticks + 1
 
         # the bank: N + 1 models made from the seed on the device
-        self.dims = tuple(reference.layer_dims(m))
+        body = harness.body(m["kind"])
         key = jax.random.PRNGKey(int(_rng(seed, 4).integers(2**31 - 1)))
-        rows = make_bank(key, dims=self.dims, rows=n_dev + 1)
-        det = AutoencoderDetector(AutoencoderConfig(
-            input_dim=int(m["input_dim"]), hidden=tuple(m["hidden"]),
-            code_dim=int(m["code_dim"]), dropout=float(m["dropout"]),
-            act=m["act"]))
-        self.row_params = rows
+        self.ref_rows = make_bank(key, body=reference.body_key(m),
+                                  rows=n_dev + 1)
+        rows = body.program_params(self.ref_rows, m)
         bank = ModelBank(
-            detector=det, topology=Topology(n_dev, k),
+            detector=as_detector(body.program_model(m)),
+            topology=Topology(n_dev, k),
             input_dim=int(m["input_dim"]),
             global_params=jax.tree.map(lambda p: p[0], rows),
             iso_params=jax.tree.map(lambda p: p[1:], rows),
@@ -230,14 +219,12 @@ class Serve:
         served by another model or at another tick than the reference
         routes them, and the widest relative gap of a served score."""
         rows = self.expected_rows()
-        ref_params = [{"w": self.row_params[f"fc{i}"]["w"],
-                       "b": self.row_params[f"fc{i}"]["b"]}
-                      for i in range(len(self.dims) - 1)]
         windows = self.pool[self.win_idx]
-        ref = reference.service_scores(ref_params, rows, windows, "highest")
+        ref = reference.service_scores(self.model, self.ref_rows, rows,
+                                       windows, "highest")
         if against_reference:
-            got = reference.service_scores(ref_params, rows, windows,
-                                           precision)
+            got = reference.service_scores(self.model, self.ref_rows, rows,
+                                           windows, precision)
             misrouted = 0
         else:
             got = self.scores

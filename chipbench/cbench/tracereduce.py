@@ -124,19 +124,25 @@ class _HostIndex:
         return best[1] if best else "other"
 
 
-def reduce_trace(rec: dict, top: int = 10) -> dict:
+def reduce_trace(rec: dict, top: int = 10,
+                 kernels: Sequence[str] = ()) -> dict:
     """Busy time (the union of op intervals, per chip, averaged), the
-    idle share, per-op device time and idle time by host span.
+    idle share, per-op device time, idle time by host span and the
+    device time of each kernel in ``kernels``.
 
     Returns ``{"window_s", "busy_s", "idle_share", "device_ops",
-    "idle_gaps", "chips"}``; ``device_ops`` and ``idle_gaps`` are lists
-    of ``[name, seconds]`` (per chip, averaged), longest first."""
+    "idle_gaps", "chips", "kernels"}``; ``device_ops`` and
+    ``idle_gaps`` are lists of ``[name, seconds]`` (per chip, averaged),
+    longest first; ``kernels`` maps each op-name prefix to
+    ``{"seconds", "calls"}``, summed over chips, of the ops named
+    ``<prefix>`` or ``<prefix>.<n>``."""
     lo, hi = rec["window"]
     planes = sorted(rec["devices"])
     if not planes:
         raise NoDevicePlane("no device plane in the trace")
     busy_tot = 0.0
     op_time: Dict[str, float] = defaultdict(float)
+    op_calls: Dict[str, int] = defaultdict(int)
     idle_by: Dict[str, float] = defaultdict(float)
     host = _HostIndex(rec["host"])
     for p in planes:
@@ -149,11 +155,19 @@ def reduce_trace(rec: dict, top: int = 10) -> dict:
             c = clip([(s, s + d)], lo, hi)
             if c and not name.startswith(CONTAINERS):
                 op_time[name] += c[0][1] - c[0][0]
+                op_calls[name] += 1
         for s, e in gaps(busy, lo, hi):
             idle_by[host.at(0.5 * (s + e))] += e - s
     n = len(planes)
     window_s = (hi - lo) * 1e-9
     busy_s = busy_tot / n * 1e-9
+    by_kernel = {}
+    for prefix in kernels:
+        names = [k for k in op_time
+                 if k == prefix or k.startswith(prefix + ".")]
+        by_kernel[prefix] = {"seconds": sum(op_time[k] for k in names)
+                             * 1e-9,
+                             "calls": sum(op_calls[k] for k in names)}
 
     def ranked(d):
         return [[k, v / n * 1e-9] for k, v in
@@ -162,4 +176,4 @@ def reduce_trace(rec: dict, top: int = 10) -> dict:
             "idle_share": 1.0 - busy_s / window_s if window_s > 0 else
             float("nan"),
             "device_ops": ranked(op_time), "idle_gaps": ranked(idle_by),
-            "chips": n}
+            "chips": n, "kernels": by_kernel}

@@ -1,15 +1,18 @@
 """The yardstick's arithmetic: the trace reduction on a small committed
-trace, the FLOP count, the table of peaks and the metric readers."""
+trace, the body's FLOP and kernel counts, the table of peaks and the
+metric readers."""
 import json
 import os
 
 import pytest
 
-from cbench import flops, peaks, tracereduce
+from cbench import harness, peaks, tracereduce
 
 HERE = os.path.dirname(os.path.abspath(__file__))
-COMMSML = {"input_dim": 112, "hidden": [128, 64], "code_dim": 32}
-CIFAR10 = {"input_dim": 3072, "hidden": [128, 64], "code_dim": 32}
+COMMSML = {"kind": "autoencoder", "input_dim": 112, "hidden": [128, 64],
+           "code_dim": 32, "act": "relu", "dropout": 0.2}
+CIFAR10 = dict(COMMSML, input_dim=3072)
+AE = harness.body("autoencoder")
 
 
 def test_trace_reduction_on_committed_trace():
@@ -31,6 +34,21 @@ def test_trace_reduction_on_committed_trace():
     # of the second plan, (520, 600) inside it
     assert dict(r["idle_gaps"]) == pytest.approx(
         {"plan": 180e-9 / 2, "execute": 130e-9 / 2})
+    assert r["kernels"] == {}
+
+
+def test_trace_reduction_sums_kernels_by_prefix():
+    with open(os.path.join(HERE, "data", "small_trace.json")) as f:
+        rec = json.load(f)
+    k = tracereduce.reduce_trace(rec, kernels=("fusion", "dot", "fus",
+                                               "absent"))["kernels"]
+    # fusion.9 clipped to 50 ns, fusion.1 twice (50 + 20), fusion.2 30;
+    # dot.3 on both chips (100 + 500), summed over chips; "fus" is no
+    # op's name or prefix before a dot
+    assert k["fusion"]["calls"] == 4
+    assert k["fusion"]["seconds"] == pytest.approx(150e-9)
+    assert k["dot"] == {"calls": 2, "seconds": pytest.approx(600e-9)}
+    assert k["fus"] == k["absent"] == {"calls": 0, "seconds": 0.0}
 
 
 def test_interval_helpers():
@@ -49,14 +67,32 @@ def test_no_device_plane_is_an_error():
 @pytest.mark.parametrize("model,count", [(COMMSML, 49680),
                                          (CIFAR10, 810400)])
 def test_param_count_matches_hand_count(model, count):
-    assert flops.param_count(model) == count
+    assert AE.param_count(model) == count
 
 
 def test_train_flops_per_scenario():
     # Comms-ML: 450 rows on each of 10 devices, E = 3, 60 rounds
-    f = flops.train_flops_per_scenario(COMMSML, [450] * 10, 3, 60)
+    f = AE.train_flops_per_scenario(COMMSML, [450] * 10, 3, 60)
     assert f == 6 * 49680 * 4500 * 3 * 60
     assert f == pytest.approx(2.414e11, rel=1e-3)
+
+
+def test_recon_out_kernel_counts():
+    """One call at the cifar cell's bucket: 6 scenarios x 10 devices x
+    1313 padded rows, width 3072, first hidden width 128."""
+    geometry = {"kind": "single", "scenarios": 6, "devices": 10,
+                "rows": 1313}
+    c = AE.kernel_counts(CIFAR10, geometry)["recon_out"]
+    assert c["flops"] == 3 * 2 * 60 * 1313 * 128 * 3072
+    assert c["flops"] == pytest.approx(1.859e11, rel=1e-3)
+    # x once per device (161.3 MB); per scenario and device W and dW,
+    # b and db, h and dh, the row weights and the loss tile (271.2 MB)
+    x = 10 * 1313 * 3072 * 4
+    per = 4 * (2 * 128 * 3072 + 2 * 3072 + 2 * 1313 * 128 + 1313 + 128)
+    assert c["bytes"] == x + 60 * per == 432_576_240
+    assert AE.kernel_counts(CIFAR10, dict(geometry, kind="multi")) == {}
+    # too narrow for the kernel (the program keeps the XLA expression)
+    assert AE.kernel_counts(COMMSML, geometry) == {}
 
 
 def test_configs_state_their_counts():
@@ -64,7 +100,8 @@ def test_configs_state_their_counts():
     for name in ("commsml-ae", "cifar10-ae"):
         with open(os.path.join(root, "configs", f"{name}.json")) as f:
             c = json.load(f)
-        assert flops.param_count(c["model"]) == c["parameters"]
+        assert harness.body(c["model"]["kind"]).param_count(c["model"]) \
+            == c["parameters"]
         assert c["matmul_precision"] == "tensorfloat32"
         for key in ("source", "reduced", "assumed", "widths"):
             assert key in c
@@ -77,24 +114,45 @@ def test_peaks_table():
         peaks.peaks("TPU v9 imaginary")
 
 
-def test_metric_readers(bench):
-    from cbench import harness
+def test_metric_readers(bench, monkeypatch):
+    from repro import obs
+    # recon_out: 4 calls of 1.97e11 FLOPs (3 passes: 3 ms each at the
+    # bf16 peak) and 0.4095 GB (0.5 ms each) in 20 ms of device time
     trace = {"busy_s": 2.0, "window_s": 10.0, "idle_share": 0.8,
-             "chips": 1}
+             "chips": 1, "kernels": {"recon_out": {"seconds": 0.02,
+                                                   "calls": 4}}}
     camp = {"kind": "campaign", "trace": trace, "window_s": 10.0,
             "chips": 1, "peaks": peaks.peaks("TPU v5 lite"),
-            "counters": {"scenarios": 40, "executions": 4, "plan_s": 0.2,
-                         "flops": 1.97e13, "exe_load_s": 7.5}}
+            "precision": "tensorfloat32",
+            "counters": {"scenarios": 40, "executions": 2, "plan_s": 0.1,
+                         "flops": 1.97e13, "exe_load_s": 7.5,
+                         "kernels": {"recon_out": {
+                             "calls": 4, "flops": 4 * 1.97e11,
+                             "bytes": 4 * 4.095e8}}}}
     serve = {"kind": "serve", "trace": trace, "window_s": 10.0, "chips": 1,
-             "peaks": None, "counters": {"windows": 1000, "batches": 50,
-                                         "exe_load_s": 1.5}}
+             "peaks": None, "precision": "tensorfloat32",
+             "counters": {"windows": 1000, "batches": 50,
+                          "exe_load_s": 1.5}}
+    # the program's registry: the window's two executions after the
+    # warm-up's
+    records = [{"build_s": 9.0, "post_s": 9.0, "gc_s": 9.0,
+                "h2d_bytes": 9e9}]
+    records += [{"build_s": b, "post_s": p, "gc_s": g, "h2d_bytes": h}
+                for b, p, g, h in ((0.05, 0.03, 0.001, 2e8),
+                                   (0.07, 0.05, 0.0, 3e8))]
+    monkeypatch.setattr(obs, "executions", lambda: list(records))
     want = {"plan_ms.campaign": (camp, 50.0),
             "device_ms_per_scenario.campaign": (camp, 50.0),
             "device_idle_share.campaign": (camp, 80.0),
             "mfu.campaign": (camp, 1.0),
+            "exe_load_s": (serve, 1.5),
+            "build_ms.campaign": (camp, 60.0),
+            "post_ms.campaign": (camp, 40.0),
+            "gc_ms.campaign": (camp, 0.5),
+            "h2d_mb.campaign": (camp, 250.0),
+            "recon_out_roofline.campaign": (camp, 60.0),
             "dispatches_per_window.serve": (serve, 0.05),
-            "device_idle_share.serve": (serve, 80.0),
-            "exe_load_s": (serve, 1.5)}
+            "device_idle_share.serve": (serve, 80.0)}
     assert {m["name"] for m in bench["per_layer"]} <= set(want)
     for name, (ctx, value) in want.items():
         assert harness.metric_reader(name)(ctx) == pytest.approx(value)
@@ -102,3 +160,19 @@ def test_metric_readers(bench):
     assert harness.metric_reader("mfu.campaign")(serve) is None
     assert harness.metric_reader("dispatches_per_window.serve")(camp) \
         is None
+    # nor the kernel's roofline where the kernel did not run
+    roofline = harness.metric_reader("recon_out_roofline.campaign")
+    assert roofline(serve) is None
+    assert roofline(dict(camp, trace=dict(trace, kernels={
+        "recon_out": {"seconds": 0.0, "calls": 0}}))) is None
+    assert roofline(dict(camp, trace=dict(trace, kernels={}))) is None
+
+
+def test_roofline_refuses_a_count_of_other_calls():
+    trace = {"kernels": {"recon_out": {"seconds": 0.02, "calls": 5}}}
+    ctx = {"kind": "campaign", "trace": trace,
+           "peaks": peaks.peaks("TPU v5 lite"), "precision": "float32",
+           "counters": {"kernels": {"recon_out": {
+               "calls": 4, "flops": 1.0, "bytes": 1.0}}}}
+    with pytest.raises(RuntimeError, match="5 calls in the trace"):
+        harness.metric_reader("recon_out_roofline.campaign")(ctx)

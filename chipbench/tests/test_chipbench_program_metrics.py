@@ -1,7 +1,6 @@
 """The readers of the program's own execution records (``repro.obs``):
 ``build_ms.campaign``, ``post_ms.campaign``, ``gc_ms.campaign`` and
-``h2d_mb.campaign``.  ``BENCHMARK.json`` does not list them yet;
-``metrics/<name>.py`` holds each, found by name like the listed ones."""
+``h2d_mb.campaign``; ``metrics/<name>.py`` holds each, found by name."""
 import math
 
 from tinyrun import run_tiny
